@@ -25,6 +25,7 @@ import os
 import time
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def component_graph(components: int, n_per: int, m_per: int,
@@ -126,6 +127,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_construction.json"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     import jax
     from repro.core.distributed import default_line_graph_mesh

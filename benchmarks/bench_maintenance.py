@@ -19,6 +19,7 @@ import os
 import time
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def _sample_queries(h, rng, q):
@@ -212,6 +213,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_maintenance.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         components = args.components or [2, 4]
         chain_len = args.chain_len or 8

@@ -28,6 +28,7 @@ import tempfile
 import time
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def _update_stream(h, k, seed=11):
@@ -154,6 +155,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_persistence.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         sizes = [(120, 150), (300, 380)]
         wal_records = args.wal_records or 4

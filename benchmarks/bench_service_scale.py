@@ -37,6 +37,7 @@ import os
 import time
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 PRIORITY_MIXES = {
     "uniform": (("standard", 1.0),),
@@ -390,6 +391,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_service_scale.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         run(n=args.n or 300, m=args.m or 100,
             queries=args.queries or 768, greedy_q=512, light_q=16,

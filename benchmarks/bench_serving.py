@@ -37,6 +37,7 @@ import os
 import time
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def _mixed_workload(h, rng, q):
@@ -230,6 +231,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_serving.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         n = args.n or 500
         m = args.m or 160

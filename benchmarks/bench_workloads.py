@@ -22,6 +22,7 @@ import os
 import time
 
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def _timed(fn, *args):
@@ -181,6 +182,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_workloads.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         sizes = [(20, 30), (30, 45), (40, 60)]
         n_queries = args.n_queries or 6

@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import (random_hypergraph, distinct_thresholds,
                         maxmin_closure, threshold_closure_mr, maxmin_matmul)
 from repro.kernels import ref
+from repro.compile_cache import enable_compile_cache
 
 __all__ = ["closure_bench", "label_join_bench", "maxmin_bench", "main"]
 
@@ -226,6 +227,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_kernels.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         run(n=200, m=160, q=512, sample=128, mm=128, out_path=args.out)
     else:

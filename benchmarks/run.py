@@ -9,11 +9,14 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from . import paper_tables as pt
     from . import kernels_bench as kb

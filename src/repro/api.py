@@ -115,11 +115,11 @@ serial ``build_fast`` (property-tested), so every downstream contract
 unchanged.  ``build_labels=True`` flips the ``sharded`` backend from the
 resident-closure regime to serving mesh-sharded label snapshots.
 
-``make_mesh`` (re-exported from ``repro.compat``) hides jax-version API
-drift; ``snap.to_mesh(mesh)`` re-lands any label snapshot sharded over a
-mesh.  The architecture — data flow, backend catalogue, planner policy,
-construction modes, and the sharding schedules — is documented in
-``docs/ARCHITECTURE.md``.
+``make_mesh`` (re-exported from ``repro.compat``) builds a mesh whose
+axes are all ``AxisType.Auto``; ``snap.to_mesh(mesh)`` re-lands any
+label snapshot sharded over a mesh.  The architecture — data flow,
+backend catalogue, planner policy, construction modes, and the
+sharding schedules — is documented in ``docs/ARCHITECTURE.md``.
 """
 from __future__ import annotations
 

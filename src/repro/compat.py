@@ -1,33 +1,19 @@
-"""Shims over jax API drift so the same source runs on the pinned
-container jax (0.4.x) and current releases.
+"""The JAX 0.9 mesh and ``shard_map`` spellings used across the package.
 
-* ``shard_map``: promoted from ``jax.experimental.shard_map`` to
-  ``jax.shard_map`` (and ``check_rep`` renamed ``check_vma``) in newer
-  releases.
-* ``make_mesh``: ``axis_types`` / ``jax.sharding.AxisType`` only exist on
-  newer releases; older meshes are Auto-typed implicitly.
+* ``shard_map``: ``jax.shard_map`` (``check_vma`` toggles the
+  varying-manual-axes check).
+* ``make_mesh``: every axis ``AxisType.Auto``, so GSPMD keeps
+  propagating shardings through the jitted programs around the
+  ``shard_map`` bodies.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-if hasattr(jax, "shard_map"):                       # new API
-    shard_map = jax.shard_map
-else:                                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
-try:
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+shard_map = jax.shard_map
 
 
 def make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))

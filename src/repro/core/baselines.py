@@ -240,7 +240,9 @@ class ThresholdComponentIndex:
 
 class MSTOracle:
     """Maximin(e_i, e_j) equals the minimum edge on the maximum-spanning-
-    forest path — an O(m α) build + O(m) per query independent oracle."""
+    forest path — an O(m α) build + O(path length) per query independent
+    oracle: each tree is rooted once, and a query climbs from both ends
+    to their common ancestor keeping the smallest edge weight seen."""
 
     def __init__(self, h: Hypergraph):
         self.h = h
@@ -254,24 +256,39 @@ class MSTOracle:
                 dsu.union(a, b_)
                 adj[a].append((b_, w))
                 adj[b_].append((a, w))
-        self.adj = adj
+        # root every tree: parent, weight of the edge to it, depth, root
+        self._parent = [-1] * h.m
+        self._up_w = [0] * h.m
+        self._depth = [0] * h.m
+        self._root = [-1] * h.m
+        for r in range(h.m):
+            if self._root[r] >= 0:
+                continue
+            self._root[r] = r
+            stack = [r]
+            while stack:
+                x = stack.pop()
+                for y, w in adj[x]:
+                    if self._root[y] < 0:
+                        self._root[y] = r
+                        self._parent[y] = x
+                        self._up_w[y] = w
+                        self._depth[y] = self._depth[x] + 1
+                        stack.append(y)
 
     def edge_mr(self, ei: int, ej: int) -> int:
         if ei == ej:
             return self.h.edge_size(ei)
-        # BFS on the forest tracking the path bottleneck
-        best = {ei: np.iinfo(np.int64).max}
-        stack = [ei]
-        while stack:
-            x = stack.pop()
-            for y, w in self.adj[x]:
-                nb = min(best[x], w)
-                if y not in best:
-                    best[y] = nb
-                    if y == ej:
-                        return int(nb)
-                    stack.append(y)
-        return 0
+        if self._root[ei] != self._root[ej]:
+            return 0
+        best = np.iinfo(np.int64).max
+        a, b = ei, ej
+        while a != b:
+            if self._depth[a] < self._depth[b]:
+                a, b = b, a
+            best = min(best, self._up_w[a])
+            a = self._parent[a]
+        return int(best)
 
     def mr(self, u: int, v: int) -> int:
         out = 0

@@ -109,8 +109,8 @@ def _local_maxmin(a: jax.Array, b: jax.Array, chunk: int = 128) -> jax.Array:
 def _local_contraction(use_kernels: bool):
     """The per-device (max,min) contraction inside a closure round:
     the scanned jnp broadcast (default), or the Pallas ``maxmin_matmul``
-    kernel (compiled on TPU, interpret-mode elsewhere) when the engine
-    was built with ``use_kernels=True``."""
+    kernel (compiled on TPU, interpreted on CPU — ``use_interpret``)
+    when the engine was built with ``use_kernels=True``."""
     if not use_kernels:
         return _local_maxmin
     from ..kernels.maxmin_matmul import maxmin_matmul_pallas
@@ -452,8 +452,8 @@ class ShardedEngine(_EngineBase):
         configure it); the closure knobs ``schedule`` / ``rounds`` are
         then unused.  ``use_kernels=True`` runs the per-device closure
         contraction through the Pallas ``maxmin_matmul`` kernel and
-        batch queries through the Pallas label join (interpret-mode
-        fallback off TPU; answers byte-identical, conformance-pinned)."""
+        batch queries through the Pallas label join (interpreted on the
+        CPU backend; answers byte-identical, conformance-pinned)."""
         if axes is None:
             axes = (("data", "model") if mesh is None
                     else tuple(mesh.axis_names[-2:]))

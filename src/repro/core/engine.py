@@ -817,8 +817,8 @@ class HLIndexEngine(_EngineBase):
 
         ``use_kernels`` answers batch queries through the Pallas
         label-join kernel (``KernelSnapshot``) instead of the XLA
-        ``batched_mr`` program — compiled on TPU, interpret-mode
-        fallback elsewhere; answers are byte-identical either way
+        ``batched_mr`` program — compiled on TPU, interpreted on the CPU
+        backend; answers are byte-identical either way
         (conformance-matrix rows pin both).
         """
         construction = _resolve_construction(construction, mesh, workers,

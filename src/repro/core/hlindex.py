@@ -489,7 +489,9 @@ def build_sharded(h: Hypergraph, *,
     ``m_final_entries``) sum to exactly the serial builder's values;
     ``m_peak_entries`` is the max over shards (≤ the serial peak, which
     interleaves components).  Extra keys: ``shards``, ``components``,
-    ``construction``.
+    ``construction``, ``pool_workers`` (forked workers the shards were
+    handed to; 0 = inline) and ``pool_fallback`` (1 when that pool
+    failed or wedged and the shards reran inline).
 
     ``num_shards`` defaults to ``workers``, else the mesh device count,
     else 1; shard counts that exceed the component count are clamped.
@@ -520,7 +522,7 @@ def build_sharded(h: Hypergraph, *,
         if minimizer is not None:
             idx = minimizer(idx)
         idx.stats.update(shards=0, components=0, construction="sharded",
-                         pool_fallback=0.0)
+                         pool_workers=0.0, pool_fallback=0.0)
         return idx
     neighbor_reused = neighbors is not None
     if neighbors is not None:
@@ -548,7 +550,9 @@ def build_sharded(h: Hypergraph, *,
 
     sub_idxs = None
     pool_fallback = False
+    pool_workers = 0
     if workers and int(workers) > 1 and len(payloads) > 1:
+        pool_workers = min(int(workers), len(payloads))
         sub_idxs = _run_shard_pool(payloads, int(workers))
         pool_fallback = sub_idxs is None
         if pool_fallback:
@@ -594,7 +598,8 @@ def build_sharded(h: Hypergraph, *,
                                       float(sub.stats.get("m_peak_entries",
                                                           0)))
     stats.update(shards=len(shards), components=int(comp.max()) + 1,
-                 construction="sharded", pool_fallback=float(pool_fallback),
+                 construction="sharded", pool_workers=float(pool_workers),
+                 pool_fallback=float(pool_fallback),
                  neighbor_reused=float(neighbor_reused))
     return HLIndex(h=h, rank=rank, perm=perm, labels_edge=le,
                    labels_rank=lr, labels_s=ls, dual_u=du, dual_s=ds,

@@ -320,9 +320,9 @@ class KernelSnapshot:
     underlying ``DeviceSnapshot`` and the view is rebuilt around the
     result, which is why this is composition rather than subclassing.
 
-    ``interpret=None`` resolves the Pallas execution mode from the host
-    (``use_interpret()``): compiled on TPU, interpreter elsewhere —
-    the automatic fallback behind the ``use_kernels=`` engine flag.
+    ``interpret=None`` resolves the Pallas execution mode from the
+    default backend (``use_interpret()``): compiled on TPU, interpreted
+    on the CPU backend the tests run on, and an error anywhere else.
     Construction validates the rank key space against the kernel's
     padding sentinels once (``validate_ranks``), so per-batch calls
     don't pay the check.
@@ -379,9 +379,9 @@ class KernelSnapshot:
         ru, su, rv, sv = _gather_rows(self.base.ranks, self.base.svals,
                                       jnp.asarray(us), jnp.asarray(vs))
         if len(self.base.ranks.devices()) > 1:
-            # mesh-sharded base: the interpreter path runs the kernel on
-            # one device, so collapse the gathered query rows (bucket ×
-            # Lmax, not the label mass) onto a single addressable device
+            # mesh-sharded base: the kernel runs on one device, so
+            # collapse the gathered query rows (bucket × Lmax, not the
+            # label mass) onto a single addressable device
             dev = next(iter(sorted(self.base.ranks.devices(),
                                    key=lambda d: d.id)))
             ru, su, rv, sv = (jax.device_put(t, dev)

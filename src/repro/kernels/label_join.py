@@ -2,18 +2,25 @@
 
 out[q] = max over common hubs of min(s_u[q], s_v[q]) — the serving-path
 inner loop.  Each query row holds two padded, rank-sorted label lists; the
-kernel evaluates the all-pairs hub-equality join on the VPU (a
-[bq, bl, bl] compare + select + reduce), which beats the sequential
-two-pointer merge on a vector unit for the label lengths the paper
-reports (avg |L| well under 128).
+kernel evaluates the all-pairs hub-equality join on the VPU, which beats
+the sequential two-pointer merge on a vector unit for the label lengths
+the paper reports (avg |L| well under 128).
 
-Tiling: grid (Q/bq, L/bl, L/bl).  The output block ``o[bq]`` is indexed
-by the query dimension only, so it stays VMEM-resident across the whole
-(j, k) label-tile sweep (initialized on the first tile, max-accumulated
-after) — the all-pairs intermediate is bounded to [bq, bl, bl] no matter
-how wide the label rows are.  That is what keeps closure-derived
-snapshots (where L = m) and heavy-tail label rows inside VMEM instead of
-materializing a [bq, L, L] cube.
+Layout: the wrapper hands the kernel transposed label tiles, [bl, bq]
+with label slots on sublanes and queries on lanes.  One v-side label
+slot is then a single sublane row ``[1, bq]`` that broadcasts along
+sublanes over the u-side tile, so each slot costs one 2-D
+compare-select-max on ``[bl, bq]`` and nothing wider.  The [bq, bl, bl]
+broadcast cube of the textbook formulation never exists (Mosaic cannot
+lay it out, and at bq=128, bl=256 it would be 32 MiB of int32).
+
+Tiling: grid (Q/bq, L/bl, L/bl).  The output block ``o[1, bq]`` is
+indexed by the query dimension only, so it stays VMEM-resident across
+the whole (j, k) label-tile sweep (initialized on the first tile,
+max-accumulated after) — the per-step working set is bounded by four
+[bl, bq] tiles no matter how wide the label rows are.  That is what
+keeps closure-derived snapshots (where L = m) and heavy-tail label rows
+inside VMEM.
 
 Sentinel contract (shared with ``DeviceSnapshot`` / ``pad_label_rows``):
 
@@ -68,13 +75,17 @@ def _kernel(ru_ref, su_ref, rv_ref, sv_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ru = ru_ref[...]                   # [bq, bl] u-side label tile j
+    ru = ru_ref[...]                   # [bl, bq] u-side label tile j
     su = su_ref[...]
-    rv = rv_ref[...]                   # [bq, bl] v-side label tile k
-    sv = sv_ref[...]
-    eq = ru[:, :, None] == rv[:, None, :]
-    cand = jnp.where(eq, jnp.minimum(su[:, :, None], sv[:, None, :]), 0)
-    o_ref[...] = jnp.maximum(o_ref[...], cand.max(axis=(1, 2)))
+
+    def one_slot(c, acc):
+        rv = rv_ref[pl.ds(c, 1), :]    # [1, bq] v-side label slot c
+        sv = sv_ref[pl.ds(c, 1), :]
+        return jnp.maximum(acc, jnp.where(ru == rv, jnp.minimum(su, sv), 0))
+
+    acc = jax.lax.fori_loop(0, rv_ref.shape[0], one_slot,
+                            jnp.zeros_like(su))
+    o_ref[...] = jnp.maximum(o_ref[...], acc.max(axis=0, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bl", "interpret"))
@@ -103,17 +114,19 @@ def label_join_pallas(ru: jax.Array, su: jax.Array, rv: jax.Array,
     qg = ru.shape[0] // bq
     lg = ru.shape[1] // bl
 
+    # queries on lanes, label slots on sublanes (see module docstring)
+    ru, su, rv, sv = ru.T, su.T, rv.T, sv.T
     out = pl.pallas_call(
         _kernel,
         grid=(qg, lg, lg),
         in_specs=[
-            pl.BlockSpec((bq, bl), lambda i, j, k: (i, j)),   # u ranks
-            pl.BlockSpec((bq, bl), lambda i, j, k: (i, j)),   # u svals
-            pl.BlockSpec((bq, bl), lambda i, j, k: (i, k)),   # v ranks
-            pl.BlockSpec((bq, bl), lambda i, j, k: (i, k)),   # v svals
+            pl.BlockSpec((bl, bq), lambda i, j, k: (j, i)),   # u ranks
+            pl.BlockSpec((bl, bq), lambda i, j, k: (j, i)),   # u svals
+            pl.BlockSpec((bl, bq), lambda i, j, k: (k, i)),   # v ranks
+            pl.BlockSpec((bl, bq), lambda i, j, k: (k, i)),   # v svals
         ],
-        out_specs=pl.BlockSpec((bq,), lambda i, j, k: (i,)),
-        out_shape=jax.ShapeDtypeStruct((ru.shape[0],), su.dtype),
+        out_specs=pl.BlockSpec((1, bq), lambda i, j, k: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, qg * bq), su.dtype),
         interpret=interpret,
     )(ru, su, rv, sv)
-    return out[:q]
+    return out[0, :q]

@@ -6,8 +6,13 @@ closure (the paper's max-reachability between hyperedges).
 TPU mapping: the MXU cannot evaluate a (max, min) contraction (it is a
 fixed multiply-accumulate array), so this kernel is VPU work.  The design
 goal is therefore *bandwidth*: stream 128-aligned A/B tiles HBM→VMEM once
-per (i, j, k) grid step and keep the [bm, kc, bn] broadcast intermediate
-small enough to live in VREG/VMEM (k is sub-tiled by ``k_chunk``).
+per (i, j, k) grid step and sweep the tile's k columns as rank-1 updates
+on the [bm, bn] accumulator: column ``A[:, k]`` broadcast along lanes,
+row ``B[k, :]`` along sublanes, one 2-D min + max each.  Every k slice is
+static, so Mosaic sees only aligned loads and 2-D vector ops (a
+``dynamic_slice`` along the lane dim does not lower).  The rank-1
+terms of ``k_chunk`` columns are max-combined into one partial before it
+meets the accumulator, which shortens the loop-carried max chain.
 
 Grid: (M/bm, N/bn, K/bk) with k innermost so the output block stays
 resident in VMEM across the k sweep (revisiting accumulation).
@@ -28,22 +33,14 @@ def _kernel(a_ref, b_ref, o_ref, *, k_chunk: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...]                      # [bm, bk]
-    b = b_ref[...]                      # [bk, bn]
-    bk = a.shape[1]
-    # ceil, not floor: bk // k_chunk drops the tail columns when bk is
-    # not a k_chunk multiple.  dynamic_slice clamps the last start index
-    # so the final chunk overlaps the previous one — exact here, because
-    # (max, min) accumulation is idempotent.
-    steps = -(-bk // k_chunk)
-
-    def body(i, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, i * k_chunk, k_chunk, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, i * k_chunk, k_chunk, axis=0)
-        c = jnp.minimum(a_c[:, :, None], b_c[None, :, :]).max(axis=1)
-        return jnp.maximum(acc, c)
-
-    acc = jax.lax.fori_loop(0, steps, body, o_ref[...])
+    bk = a_ref.shape[1]
+    acc = o_ref[...]                    # [bm, bn]
+    for k0 in range(0, bk, k_chunk):    # static: the tail chunk is short
+        part = None
+        for k in range(k0, min(k0 + k_chunk, bk)):
+            c = jnp.minimum(a_ref[:, k:k + 1], b_ref[k:k + 1, :])
+            part = c if part is None else jnp.maximum(part, c)
+        acc = jnp.maximum(acc, part)
     o_ref[...] = acc
 
 

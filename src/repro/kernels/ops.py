@@ -1,20 +1,18 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; on CPU (this container) they run
-under ``interpret=True`` — same kernel body, Python-evaluated, used by the
-test suite to validate against ``ref.py``.  Set ``REPRO_FORCE_REF=1`` to
-bypass Pallas entirely (pure-jnp fallbacks).
+On TPU the compiled kernels run natively; on the CPU backend (the test
+suite) they run under ``interpret=True`` — same kernel body,
+Python-evaluated, validated against ``ref.py``.  Any other backend has
+no way to run them and raises.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ref
 from .maxmin_matmul import maxmin_matmul_pallas
 from .overlap import overlap_pallas
 from .threshold_closure import threshold_step_pallas
@@ -26,7 +24,15 @@ __all__ = ["maxmin_matmul", "overlap", "threshold_step", "label_join",
 
 
 def use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on TPU, interpreted on CPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"the default JAX backend is {backend!r}")
 
 
 _INTERPRET_PROBE: Optional[bool] = None
@@ -57,34 +63,22 @@ def interpret_available() -> bool:
     return _INTERPRET_PROBE
 
 
-def _force_ref() -> bool:
-    return os.environ.get("REPRO_FORCE_REF", "0") == "1"
-
-
 def maxmin_matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128):
-    if _force_ref():
-        return ref.maxmin_matmul_ref(a, b)
     return maxmin_matmul_pallas(a, b, bm=bm, bn=bn, bk=bk,
                                 interpret=use_interpret())
 
 
 def overlap(b_inc, *, bm: int = 128, bn: int = 128, bk: int = 128):
-    if _force_ref():
-        return ref.overlap_ref(b_inc)
     return overlap_pallas(b_inc, bm=bm, bn=bn, bk=bk,
                           interpret=use_interpret())
 
 
 def threshold_step(r, *, bm: int = 128, bn: int = 128, bk: int = 128):
-    if _force_ref():
-        return ref.threshold_step_ref(r)
     return threshold_step_pallas(r, bm=bm, bn=bn, bk=bk,
                                  interpret=use_interpret())
 
 
 def label_join(ru, su, rv, sv, *, bq: int = 128):
-    if _force_ref():
-        return ref.label_join_ref(ru, su, rv, sv)
     return label_join_pallas(ru, su, rv, sv, bq=bq,
                              interpret=use_interpret())
 
