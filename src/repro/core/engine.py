@@ -92,6 +92,7 @@ from .frontier import (SparseLineGraph, frontier_batched_mr,
 from .baselines import (ETEIndex, MSTOracle, ThresholdComponentIndex,
                         build_ete)
 from .semiring import mr_matrix, vertex_mr_from_edge_mr
+from ..stages import Stages
 
 __all__ = [
     "ReachabilityEngine", "DeviceSnapshot", "KernelSnapshot",
@@ -280,6 +281,9 @@ class _EngineBase:
         # per-(s, extra_landmarks) DistanceOracle cache; invalidated on
         # every graph change (_graph_changed)
         self._distance_oracles: Dict[Tuple[int, int], "DistanceOracle"] = {}
+        # construction stage totals (``repro.build`` spans), filled by
+        # the backends whose ``build`` times its stages
+        self.build_stages = Stages("repro")
 
     @classmethod
     def build(cls, h: Hypergraph, **opts) -> "ReachabilityEngine":
@@ -824,23 +828,35 @@ class HLIndexEngine(_EngineBase):
         construction = _resolve_construction(construction, mesh, workers,
                                              num_shards)
         minimizer = minimize if minimize_labels else None
-        if construction == "sharded":
-            builder = functools.partial(build_sharded, workers=workers,
-                                        num_shards=num_shards)
-            if index is not None:
-                idx = minimizer(index) if minimizer else index
+        stages = Stages("repro")
+        with stages.span("build", backend=cls.name,
+                         construction=construction):
+            if construction == "sharded":
+                builder = functools.partial(build_sharded, workers=workers,
+                                            num_shards=num_shards)
+                if index is not None:
+                    with stages.span("build.minimize"):
+                        idx = minimizer(index) if minimizer else index
+                else:
+                    # minimization runs inside the shards too (exact:
+                    # dual sets are component-confined), so the whole
+                    # build parallelizes — byte-identical to
+                    # minimize(build_fast(h))
+                    with stages.span("build.labels"):
+                        idx = build_sharded(h, minimizer=minimizer,
+                                            workers=workers,
+                                            num_shards=num_shards, mesh=mesh)
             else:
-                # minimization runs inside the shards too (exact: dual
-                # sets are component-confined), so the whole build
-                # parallelizes — byte-identical to minimize(build_fast(h))
-                idx = build_sharded(h, minimizer=minimizer, workers=workers,
-                                    num_shards=num_shards, mesh=mesh)
-        else:
-            builder = build_fast
-            idx = index if index is not None else build_fast(h)
-            if minimizer is not None:
-                idx = minimizer(idx)
+                builder = build_fast
+                if index is None:
+                    with stages.span("build.labels"):
+                        index = build_fast(h, stages=stages)
+                idx = index
+                if minimizer is not None:
+                    with stages.span("build.minimize"):
+                        idx = minimizer(idx)
         eng = cls(h, idx, builder=builder, minimizer=minimizer)
+        eng.build_stages = stages
         eng.construction = construction
         eng.use_kernels = bool(use_kernels)
         return eng
@@ -1156,7 +1172,12 @@ class ClosureEngine(_EngineBase):
 
     @classmethod
     def build(cls, h: Hypergraph, *, method: str = "maxmin") -> "ClosureEngine":
-        return cls(h, mr_matrix(h, method=method), method)
+        stages = Stages("repro")
+        with stages.span("build", backend=cls.name, method=method):
+            w_star = mr_matrix(h, method=method, stages=stages)
+        eng = cls(h, w_star, method)
+        eng.build_stages = stages
+        return eng
 
     def _apply_update(self, inserts=(), deletes=()) -> None:
         # dense closures have no cheap incremental form (one new overlap

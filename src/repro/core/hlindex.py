@@ -31,6 +31,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import multiprocessing
+import time
 import warnings
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -38,6 +39,7 @@ import numpy as np
 
 from .hypergraph import Hypergraph, NeighborCSR, induced_subhypergraph, \
     neighbor_csr
+from ..stages import Stages
 
 __all__ = ["HLIndex", "build_basic", "build_fast", "build_sharded",
            "pad_label_rows", "splice_rank", "CONSTRUCTION_MODES"]
@@ -296,11 +298,18 @@ def build_basic(h: Hypergraph, cover_check: bool = True, *,
 # ---------------------------------------------------------------------------
 
 def build_fast(h: Hypergraph, *,
-               neighbors: Optional[NeighborCSR] = None) -> HLIndex:
+               neighbors: Optional[NeighborCSR] = None,
+               stages: Optional[Stages] = None) -> HLIndex:
     """Algorithm 3.  ``neighbors`` is an optional precomputed
     ``NeighborCSR`` used for the one-shot M initialization (Lemma 6)
     instead of computing ``N(e)`` on the fly — the output is identical
-    either way (the CSR rows are byte-equal to ``neighbors_od``)."""
+    either way (the CSR rows are byte-equal to ``neighbors_od``).
+
+    ``stages`` receives ``build.neighbors`` (the neighbor-index
+    initializations, summed, one count each) and ``build.finish`` (the
+    label lists turned into arrays)."""
+    stages = stages if stages is not None else Stages("repro")
+    clock, thread_clock = time.perf_counter_ns, time.thread_time_ns
     b = _Builder(h)
     rank, sizes = b.rank, b.sizes
     mcd = np.zeros(h.m, np.int64)
@@ -323,6 +332,9 @@ def build_fast(h: Hypergraph, *,
                 mcd[e_u] = s                         # line 9
             b.add_labels(root, e_u, s)               # lines 10-13
             if M[e_u] is None:                       # lines 14-18
+                cpu0 = (thread_clock() if stages.reads_cpu("build.neighbors")
+                        else None)
+                wall0 = clock()
                 b.stats["neighbor_inits"] += 1
                 entries: Dict[int, int] = {}
                 nb, od = (neighbors.row(e_u) if neighbors is not None
@@ -336,6 +348,9 @@ def build_fast(h: Hypergraph, *,
                 m_entries += len(entries)
                 b.stats["m_total_inserts"] += len(entries)
                 b.stats["m_peak_entries"] = max(b.stats["m_peak_entries"], m_entries)
+                wall = clock() - wall0
+                stages.add("build.neighbors", wall, None if cpu0 is None
+                           else thread_clock() - cpu0)
             evict: List[int] = []
             for e_v, w in M[e_u].items():            # lines 19-24
                 if (w > mcd_root and b.visited_e[e_v] != root
@@ -352,7 +367,8 @@ def build_fast(h: Hypergraph, *,
                     del other[e_u]
                     m_entries -= 1
     b.stats["m_final_entries"] = m_entries
-    return b.finish()
+    with stages.span("build.finish"):
+        return b.finish()
 
 
 # ---------------------------------------------------------------------------

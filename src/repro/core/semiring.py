@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hypergraph import Hypergraph
+from ..stages import Stages
 
 __all__ = [
     "maxmin_matmul", "maxmin_closure", "boolean_closure",
@@ -164,16 +165,27 @@ def threshold_closure_mr(w: jax.Array, thresholds: Optional[np.ndarray] = None,
     return mr
 
 
-def mr_matrix(h: Hypergraph, *, method: str = "maxmin") -> np.ndarray:
-    """Hyperedge-level MR matrix W* for a whole hypergraph."""
+def mr_matrix(h: Hypergraph, *, method: str = "maxmin",
+              stages: Optional[Stages] = None) -> np.ndarray:
+    """Hyperedge-level MR matrix W* for a whole hypergraph.
+
+    ``stages`` receives ``build.line_graph`` (the overlap matrix, made
+    on the host and uploaded), ``build.closure`` (compiling and running
+    the closure's squaring rounds on the device, to completion) and
+    ``build.fetch`` (W* copied back to the host)."""
     if h.m == 0:                # no hyperedges: nothing is reachable
         return np.zeros((0, 0), np.int32)
-    w = jnp.asarray(h.line_graph(np.int32))
-    if method == "maxmin":
-        return np.asarray(maxmin_closure(w))
-    if method == "threshold":
-        return np.asarray(threshold_closure_mr(w)).astype(np.int32)
-    raise ValueError(method)
+    if method not in ("maxmin", "threshold"):
+        raise ValueError(method)
+    stages = stages if stages is not None else Stages("repro")
+    with stages.span("build.line_graph", m=h.m):
+        w = jnp.asarray(h.line_graph(np.int32))
+    rounds = max(1, int(np.ceil(np.log2(max(h.m, 2)))))
+    with stages.span("build.closure", method=method, rounds=rounds):
+        w_star = (maxmin_closure(w) if method == "maxmin"
+                  else threshold_closure_mr(w)).block_until_ready()
+    with stages.span("build.fetch"):
+        return np.asarray(w_star).astype(np.int32, copy=False)
 
 
 def vertex_mr_from_edge_mr(h: Hypergraph, w_star: np.ndarray,

@@ -86,6 +86,7 @@ from repro.core.engine import SnapshotUnsupported, WorkloadUnsupported
 from repro.core.query import KernelSnapshot
 from repro.serve.scheduler import (PRIORITY_CLASSES, DeadlineExceeded,
                                    TenantSpec, WeightedFairScheduler, _Entry)
+from repro.stages import Stages, StageTotal
 
 __all__ = ["Request", "MRRequest", "SReachRequest", "WitnessRequest",
            "SReachKRequest", "MRSetRequest", "TopSRequest",
@@ -279,7 +280,13 @@ class ServiceConfig:
 
 @dataclasses.dataclass
 class ServiceStats:
-    """Counters the admission loop maintains (read via ``stats()``)."""
+    """Counters the admission loop maintains (read via ``stats()``).
+
+    ``stages`` holds the totals of the service's stage spans
+    (``repro.serve.<stage>``, see ``ReachabilityService``) as
+    ``repro.stages.StageTotal``: per stage, runs, wall seconds and the
+    running thread's CPU share.  ``queue_wait_s / queued`` is the mean
+    time a request waited in the queue before a take selected it."""
 
     submitted: int = 0
     answered: int = 0
@@ -298,12 +305,15 @@ class ServiceStats:
     workload_answered: Dict[str, int] = dataclasses.field(
         default_factory=dict)        # per-kind workload answers served
     updates: int = 0
+    queued: int = 0                  # requests taken off the queue
+    queue_wait_s: float = 0.0        # their summed time in the queue
+    stages: Dict[str, StageTotal] = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> Dict[str, object]:
         d = dataclasses.asdict(self)
         for key in ("bucket_histogram", "tenant_submitted",
                     "tenant_answered", "tenant_expired",
-                    "workload_answered"):
+                    "workload_answered", "stages"):
             d[key] = dict(sorted(d[key].items()))
         return d
 
@@ -351,6 +361,20 @@ class ReachabilityService:
     flips both build and serving.  The kernel view shares this service's
     admission buckets (``min_bucket``), so traffic compiles one kernel
     program per bucket shape.
+
+    Stage spans (``repro.stages``): the thread that dispatches (the
+    admission thread, or the caller of ``drain``) times each stage into
+    ``stats().stages`` and, while a profiler runs, annotates it as
+    ``repro.serve.<stage>``: ``wait`` (queue empty), ``linger`` (the
+    ``max_wait_ms`` coalescing wait), ``take`` (selection off the queue
+    and failing expired requests; meta ``batch``, ``taken``, ``wait_s``,
+    the taken requests' summed queue wait), ``dispatch`` (one take's
+    micro-batch; meta ``batch``, the take's), and inside it ``refresh``
+    (the snapshot swap), then per kind group ``prepare`` (ids into
+    arrays, padding; meta ``kind``, ``q``, ``bucket``), ``join`` (the
+    device call through the fetch of its answers; meta ``bucket``) and
+    ``resolve`` (futures and their callbacks; meta ``q``).  ``update``
+    is timed on the updating thread while it holds the dispatch lock.
     """
 
     # ReplicaGroup flips this; a plain service refuses a replicated
@@ -386,6 +410,8 @@ class ReachabilityService:
         self.min_bucket = cfg.min_bucket
         self.max_wait_s = cfg.max_wait_ms / 1e3
         self._stats = ServiceStats()
+        self._stages = Stages("repro.serve")
+        self._batch_seq = 0          # numbers takes; a take's dispatch shares it
         self._queue = WeightedFairScheduler(
             cfg.tenants, default_weight=cfg.default_weight,
             quantum=cfg.quantum)
@@ -617,7 +643,7 @@ class ReachabilityService:
         """Apply hyperedge edits through the engine.  Serving continues:
         the stale resident snapshot keeps answering until the admission
         loop swaps in the refreshed one before the next micro-batch."""
-        with self._dispatch_lock:
+        with self._dispatch_lock, self._stages.span("update"):
             self.engine.update(inserts, deletes)
             self._stats.updates += 1
 
@@ -665,7 +691,8 @@ class ReachabilityService:
                 tenant_submitted=dict(self._stats.tenant_submitted),
                 tenant_answered=dict(self._stats.tenant_answered),
                 tenant_expired=dict(self._stats.tenant_expired),
-                workload_answered=dict(self._stats.workload_answered))
+                workload_answered=dict(self._stats.workload_answered),
+                stages=self._stages.totals())
 
     def pending(self) -> int:
         with self._cv:
@@ -679,27 +706,30 @@ class ReachabilityService:
     # -- admission loop ----------------------------------------------------
 
     def _loop(self) -> None:
+        stages = self._stages
         while True:
             with self._cv:
-                while self._running and not len(self._queue):
-                    self._cv.wait(timeout=0.05)
+                if self._running and not len(self._queue):
+                    with stages.span("wait"):
+                        while self._running and not len(self._queue):
+                            self._cv.wait(timeout=0.05)
                 if not self._running and not len(self._queue):
                     return
-                # linger for the full coalescing window (each submit()
-                # notify wakes the wait, so loop until the deadline or a
-                # full batch) — the latency/throughput admission knob
-                deadline = time.monotonic() + self.max_wait_s
-                while (self._running
-                        and len(self._queue) < self.max_batch):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cv.wait(timeout=remaining)
-                batch, expired = self._queue.take(self.max_batch,
-                                                  time.monotonic())
-            self._fail_expired(expired)
+                if self.max_wait_s > 0:
+                    # linger for the full coalescing window (each submit()
+                    # notify wakes the wait, so loop until the deadline or
+                    # a full batch) — the latency/throughput admission knob
+                    with stages.span("linger"):
+                        deadline = time.monotonic() + self.max_wait_s
+                        while (self._running
+                                and len(self._queue) < self.max_batch):
+                            remaining = deadline - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            self._cv.wait(timeout=remaining)
+            seq, batch, _ = self._take()
             if batch:
-                self._dispatch(batch)
+                self._dispatch(batch, seq)
 
     def drain(self, max_batches: Optional[int] = None) -> int:
         """Synchronously dispatch pending requests in the caller's
@@ -711,17 +741,36 @@ class ReachabilityService:
         total = 0
         batches = 0
         while max_batches is None or batches < max_batches:
-            with self._cv:
-                batch, expired = self._queue.take(self.max_batch,
-                                                  time.monotonic())
-            self._fail_expired(expired)
+            seq, batch, expired = self._take()
             if not batch and not expired:
                 return total
             if batch:
-                self._dispatch(batch)
+                self._dispatch(batch, seq)
                 batches += 1
             total += len(batch) + len(expired)
         return total
+
+    def _take(self) -> Tuple[int, List[_Entry], List[_Entry]]:
+        """Select the next micro-batch off the queue and fail the
+        requests whose deadline passed.  Returns the take's number, the
+        batch and the expired entries.  Adds the batch's time in the
+        queue to ``queue_wait_s``: one subtraction per request."""
+        self._batch_seq += 1
+        seq = self._batch_seq
+        with self._stages.span("take", batch=seq) as span:
+            with self._cv:
+                now = time.monotonic()
+                batch, expired = self._queue.take(self.max_batch, now)
+            waited = 0.0
+            for entry in batch:
+                waited += now - entry.enqueued
+            if batch:
+                with self._dispatch_lock:
+                    self._stats.queued += len(batch)
+                    self._stats.queue_wait_s += waited
+            self._fail_expired(expired)
+            span.set(taken=len(batch), wait_s=waited)
+        return seq, batch, expired
 
     def _fail_expired(self, expired: List[_Entry]) -> None:
         if not expired:
@@ -743,10 +792,12 @@ class ReachabilityService:
 
     # -- dispatch ----------------------------------------------------------
 
-    def _dispatch(self, batch: List[_Entry]) -> None:
+    def _dispatch(self, batch: List[_Entry], seq: int) -> None:
         try:
-            with self._dispatch_lock:
-                snap = self._refresh_snapshot()
+            with self._stages.span("dispatch", batch=seq), \
+                    self._dispatch_lock:
+                with self._stages.span("refresh"):
+                    snap = self._refresh_snapshot()
                 groups: Dict[str, List[_Entry]] = {}
                 for entry in batch:
                     groups.setdefault(entry.request.kind, []).append(entry)
@@ -766,43 +817,52 @@ class ReachabilityService:
         if kind in _KIND_TO_OP:
             self._dispatch_workload_group(kind, group)
             return
+        stages = self._stages
         q = len(group)
-        us = np.fromiter((e.request.u for e in group), np.int64, q)
-        vs = np.fromiter((e.request.v for e in group), np.int64, q)
         bucket = _bucket_size(q, self.min_bucket, self.max_batch)
-        if bucket > q:
-            # pad with a repeat of the first (real, validated) pair —
-            # inert: answers past q are dropped before the scatter
-            us = np.concatenate([us, np.full(bucket - q, us[0])])
-            vs = np.concatenate([vs, np.full(bucket - q, vs[0])])
-        self._stats.batches += 1
-        self._stats.padded_queries += bucket - q
-        self._stats.bucket_histogram[bucket] = \
-            self._stats.bucket_histogram.get(bucket, 0) + 1
-        if isinstance(snap, KernelSnapshot):
-            self._stats.kernel_batches += 1
+        with stages.span("prepare", kind=kind, q=q, bucket=bucket):
+            us = np.fromiter((e.request.u for e in group), np.int64, q)
+            vs = np.fromiter((e.request.v for e in group), np.int64, q)
+            if bucket > q:
+                # pad with a repeat of the first (real, validated) pair —
+                # inert: answers past q are dropped before the scatter
+                us = np.concatenate([us, np.full(bucket - q, us[0])])
+                vs = np.concatenate([vs, np.full(bucket - q, vs[0])])
+            if kind != "mr":
+                svals = np.fromiter((e.request.s for e in group), np.int64,
+                                    q)
+            self._stats.batches += 1
+            self._stats.padded_queries += bucket - q
+            self._stats.bucket_histogram[bucket] = \
+                self._stats.bucket_histogram.get(bucket, 0) + 1
+            if isinstance(snap, KernelSnapshot):
+                self._stats.kernel_batches += 1
 
         if kind == "mr":
-            if snap is not None:
-                mr = np.asarray(snap.mr(us, vs))[:q]
-            else:
-                mr = np.asarray(self.engine.mr_batch(us, vs))[:q]
-            for entry, val in zip(group, mr):
-                _resolve(entry.future, int(val))
+            with stages.span("join", bucket=bucket):
+                if snap is not None:
+                    mr = np.asarray(snap.mr(us, vs))[:q]
+                else:
+                    mr = np.asarray(self.engine.mr_batch(us, vs))[:q]
+            with stages.span("resolve", q=q):
+                for entry, val in zip(group, mr):
+                    _resolve(entry.future, int(val))
             return
 
-        svals = np.fromiter((e.request.s for e in group), np.int64, q)
-        if snap is not None:
-            # one fused join answers every s at once: s_reach == mr >= s
-            ok = np.asarray(snap.mr(us, vs))[:q] >= svals
-        elif svals.size and (svals == svals[0]).all():
-            # uniform s: the backend's native (possibly cheaper) batch path
-            ok = np.asarray(
-                self.engine.s_reach_batch(us, vs, int(svals[0])))[:q]
-        else:
-            ok = np.asarray(self.engine.mr_batch(us, vs))[:q] >= svals
-        for entry, val in zip(group, ok):
-            _resolve(entry.future, bool(val))
+        with stages.span("join", bucket=bucket):
+            if snap is not None:
+                # one fused join answers every s at once: s_reach == mr >= s
+                ok = np.asarray(snap.mr(us, vs))[:q] >= svals
+            elif svals.size and (svals == svals[0]).all():
+                # uniform s: the backend's native (possibly cheaper) batch
+                # path
+                ok = np.asarray(
+                    self.engine.s_reach_batch(us, vs, int(svals[0])))[:q]
+            else:
+                ok = np.asarray(self.engine.mr_batch(us, vs))[:q] >= svals
+        with stages.span("resolve", q=q):
+            for entry, val in zip(group, ok):
+                _resolve(entry.future, bool(val))
 
     def _dispatch_workload_group(self, kind: str, group: List[_Entry]) -> None:
         """Workload kinds dispatch per-request through the engine's
