@@ -334,7 +334,7 @@ class ShardedEngine(_EngineBase):
     through one host round-trip and the snapshot survives across query
     batches.  Same exactness argument as the single-device ``closure``
     backend: every hyperedge is a hub, and the bottleneck triangle
-    inequality makes the shared searchsorted join exact on these rows.
+    inequality makes the shared label join exact on these rows.
 
     Mesh handling: ``mesh=None`` builds a near-square 2-D mesh over all
     visible devices (``default_line_graph_mesh``); unit axes degrade to

@@ -1154,7 +1154,7 @@ class ClosureEngine(_EngineBase):
 
     Its snapshot is the degenerate-but-exact label form: every hyperedge
     is a hub, ``L(u)[e] = max_{e_u ∋ u} W*[e_u, e]``.  Bottleneck triangle
-    inequality makes the shared searchsorted join exact on these rows
+    inequality makes the shared label join exact on these rows
     (equality is attained at the hub e = e_u of an optimal pair).
     """
 

@@ -1,17 +1,22 @@
-"""Query kernels: the Pallas label-join vs the merge-join reference.
+"""Query kernels: the Pallas label-join and the XLA ``batched_mr`` join
+vs the merge-join reference.
 
 The padded-engine-vs-oracle equivalence checks that used to live here
 are conformance matrix cells now (tests/test_conformance.py: the
 ``snapshot`` operation and the PaddedIndex back-compat test); this file
 keeps the kernel-specific coverage.
 """
+import types
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core import (random_hypergraph, build_fast, minimize,
-                        PaddedIndex, mr_oracle_dense)
-from repro.kernels import label_join
+                        PaddedIndex, mr_oracle_dense, batched_mr, mr_query)
+from repro.core.hlindex import pad_label_rows
+from repro.kernels import label_join, MAX_RANK
 
 
 @pytest.fixture(scope="module")
@@ -44,3 +49,70 @@ def test_empty_labels_queries():
     assert mr_query(idx, 3, 4) == 0
     pidx = PaddedIndex(idx)
     assert int(pidx.mr(np.array([3]), np.array([0]))[0]) == 0
+
+
+@jax.jit
+def _searchsorted_mr(ranks, svals, us, vs):
+    """The join ``batched_mr`` used before its sort-merge form: a binary
+    search of each of u's keys in v's sorted row, kept as a second
+    reference."""
+    ru, su, rv, sv = ranks[us], svals[us], ranks[vs], svals[vs]
+    pos = jax.vmap(jnp.searchsorted)(rv, ru)
+    pos = jnp.minimum(pos, rv.shape[1] - 1)
+    hit = jnp.take_along_axis(rv, pos, axis=1) == ru
+    sv_at = jnp.take_along_axis(sv, pos, axis=1)
+    return jnp.where(hit, jnp.minimum(su, sv_at), 0).max(axis=1)
+
+
+def _label_rows(case, rng):
+    """Ragged (ascending keys, s) label rows for one join case."""
+    n = 24
+    if case == "closure_dense":      # every hyperedge a hub, every row full
+        m = 45
+        return ([np.arange(m, dtype=np.int32) for _ in range(n)],
+                [rng.integers(0, 6, m).astype(np.int32) for _ in range(n)])
+    width, key_lo, key_hi = {"lmax1": (1, 0, 4),
+                             "width37": (37, 0, 120),
+                             "width588": (588, 0, 1500),
+                             "all_padding_rows": (20, 0, 60),
+                             "u_equals_v": (30, 0, 90),
+                             "max_rank_keys": (30, MAX_RANK - 80,
+                                               MAX_RANK + 1)}[case]
+    lengths = rng.integers(0, width + 1, n)
+    lengths[:2] = width                             # the width is reached
+    if case == "all_padding_rows":
+        lengths[2::2] = 0
+    ranks = [np.sort(rng.choice(np.arange(key_lo, key_hi), size=c,
+                                replace=False)).astype(np.int32)
+             for c in lengths]
+    if case == "max_rank_keys":                     # the extremes occur
+        ranks[0][-1] = ranks[1][-1] = MAX_RANK
+        ranks[0][0] = ranks[1][0] = 0
+    svals = [rng.integers(1, 9, c).astype(np.int32) for c in lengths]
+    return ranks, svals
+
+
+@pytest.mark.parametrize("bucket", [8, 64])
+@pytest.mark.parametrize("case", ["lmax1", "width37", "width588",
+                                  "closure_dense", "all_padding_rows",
+                                  "u_equals_v", "max_rank_keys"])
+def test_batched_mr_exact(case, bucket):
+    # the sort-merge join against Algorithm 5 and the searchsorted join
+    rng = np.random.default_rng([bucket, len(case)])
+    row_ranks, row_svals = _label_rows(case, rng)
+    ranks, svals, _ = pad_label_rows(row_ranks, row_svals)
+    idx = types.SimpleNamespace(labels_rank=row_ranks, labels_s=row_svals)
+    n = len(row_ranks)
+    us = rng.integers(0, n, bucket).astype(np.int32)
+    vs = rng.integers(0, n, bucket).astype(np.int32)
+    vs[:4] = us[:4]
+    if case == "u_equals_v":
+        vs = us.copy()
+    if case == "all_padding_rows":
+        us[4:8], vs[4:8] = 2, np.array([4, 0, 1, 6])   # empty vs empty/full
+    got = np.asarray(batched_mr(jnp.asarray(ranks), jnp.asarray(svals),
+                                jnp.asarray(us), jnp.asarray(vs)))
+    want = np.array([mr_query(idx, u, v) for u, v in zip(us, vs)])
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        got, np.asarray(_searchsorted_mr(ranks, svals, us, vs)))

@@ -83,6 +83,20 @@ def test_batched_mr_compiles(one_chip):
     assert stats.temp_size_in_bytes < 64 * 2**20
 
 
+@pytest.mark.parametrize("n, width, q, temp_bound", [
+    (327, 7818, 4096, 3 * 2**30),      # closure rows, a full 4,096 bucket
+    (11_107, 588, 64, 64 * 2**20),     # HL-index rows, an open-loop bucket
+])
+def test_batched_mr_compiles_loop_free(one_chip, n, width, q, temp_bound):
+    # the join is one program with no loop: a per-element search loop
+    # (one gather per slot per step) is what made the chip crawl
+    labels = _spec((n, width), jnp.int32, one_chip)
+    queries = _spec((q,), jnp.int32, one_chip)
+    compiled = batched_mr.lower(labels, labels, queries, queries).compile()
+    assert "while" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
+
+
 @pytest.mark.parametrize("schedule", ["allgather", "ring"])
 def test_sharded_round_with_kernels_compiles(topo, monkeypatch, schedule):
     # the closure round picks interpret mode from the default backend,
