@@ -12,7 +12,7 @@ import jax
 import numpy as np
 
 from repro.api import build_engine, plan_backend, random_hypergraph
-from repro.core import distinct_thresholds
+from repro.core import distinct_thresholds, mr_matrix
 from repro.core.distributed import (sharded_maxmin_closure,
                                     sharded_threshold_closure_mr,
                                     collective_bytes_of, sharded_maxmin_round,
@@ -29,10 +29,9 @@ def main():
     w = h.line_graph(np.int32).astype(np.float32)
     print(f"hypergraph: n={h.n} m={h.m}; line graph {w.shape}")
 
-    # the facade's closure backend is the single-device reference: its W*
-    # is exactly what the sharded closures must reproduce
-    closure_eng = build_engine(h, backend="closure")
-    dense = closure_eng.w_star.astype(np.float32)
+    # the single-device squaring closure is the reference: its W* is
+    # exactly what the sharded closures must reproduce
+    dense = mr_matrix(h).astype(np.float32)
 
     for sched in ("allgather", "ring"):
         t0 = time.perf_counter()

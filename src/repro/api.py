@@ -11,7 +11,13 @@
     snap.mr(us, vs)                  # fused XLA batch join
 
 Every backend (see ``available_backends()``) answers through the same
-``ReachabilityEngine`` protocol; ``backend="auto"`` lets the planner pick.
+``ReachabilityEngine`` protocol; ``backend="auto"`` lets the planner
+(``plan_backend``) pick: ``hl-index`` while the label build is cheap;
+past that, ``closure`` when its device working set (the overlap matrix,
+the ``[n, m]`` closure rows and one full join) fits three quarters of
+the device's reported memory and its rows build (about n·m² per round)
+fits ``CLOSURE_BUILD_BUDGET``, the rows then being propagated on the
+device; else the index-free ``frontier`` (batches) or ``online``.
 Examples, benchmarks, and the cross-validation suite all route through
 this module, so a new backend is one ``register_backend`` entry away from
 being benchmarked and validated.
@@ -191,7 +197,8 @@ def serve(h_or_engine, backend: str = "auto", *,
       backend / batch_hint / mesh / engine ``**opts``: forwarded to
         ``build_engine`` when a hypergraph is passed.  ``mesh`` is also
         handed to the service so the resident snapshot is kept
-        mesh-sharded.
+        mesh-sharded.  With ``backend="auto"`` the planner reserves
+        room for a join of ``config.max_batch`` queries.
       start: start the background admission thread (``start=False`` =
         synchronous mode; call ``svc.drain()``).
 
@@ -226,7 +233,8 @@ def serve(h_or_engine, backend: str = "auto", *,
         # the caller — picked it)
         resolved = backend if backend != "auto" else plan_backend(
             h_or_engine, batch_hint, mesh=mesh,
-            device_budget_bytes=opts.get("device_budget_bytes"))
+            device_budget_bytes=opts.get("device_budget_bytes"),
+            join_batch=cfg.max_batch)
         if cfg.axes is not None and resolved == "sharded":
             opts["axes"] = cfg.axes  # same axes in both layers
         engine = build_engine(h_or_engine, resolved, batch_hint=batch_hint,

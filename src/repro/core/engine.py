@@ -91,7 +91,7 @@ from .frontier import (SparseLineGraph, frontier_batched_mr,
                        frontier_batched_s_reach)
 from .baselines import (ETEIndex, MSTOracle, ThresholdComponentIndex,
                         build_ete)
-from .semiring import mr_matrix, vertex_mr_from_edge_mr
+from .semiring import vertex_rows
 from ..stages import Stages
 
 __all__ = [
@@ -103,7 +103,10 @@ __all__ = [
     "build", "validate_batch",
     "HLIndexEngine", "OnlineEngine", "FrontierEngine", "ETEEngine",
     "ThresholdEngine", "MSTOracleEngine", "ClosureEngine",
-    "SINGLE_DEVICE_CLOSURE_BUDGET", "CONSTRUCTION_MODES",
+    "SINGLE_DEVICE_CLOSURE_BUDGET", "CLOSURE_JOIN_BATCH",
+    "CLOSURE_BUILD_BUDGET", "closure_build_pairs",
+    "closure_working_set_bytes", "device_memory_budget",
+    "CONSTRUCTION_MODES",
 ]
 
 
@@ -144,7 +147,48 @@ def validate_batch(us, vs, n: int):
 # Per-device byte budget for the dense closure working set (operand plus
 # the two gathered panels, f32).  When a multi-device mesh is passed and
 # 12·m² exceeds this, the auto planner routes to the "sharded" backend.
+# The single-device closure route reads the device's own memory instead,
+# and falls back to this where the device reports none (the CPU).
 SINGLE_DEVICE_CLOSURE_BUDGET = 256 * 2**20
+
+# Query batch the closure route reserves join room for when the caller
+# names none: the service's default ``max_batch``.
+CLOSURE_JOIN_BATCH = 4096
+
+# (max, min) pair operations the single-device closure route may spend
+# building its rows: about 20 s of set-up on one TPU v5e, which runs a
+# 6.6e11-pair round of email-Eu's rows in 0.66 s.
+CLOSURE_BUILD_BUDGET = 2e13
+
+
+def closure_working_set_bytes(n: int, m: int, join_batch: int) -> int:
+    """Device bytes of the ``closure`` regime, its parts summed: the
+    overlap matrix (int32 [m, m]), the rows and their snapshot keys (two
+    int32 [n, m]), and one join of ``join_batch`` queries (keys and
+    s-values of both gathered rows, and their sorted copies: four int32
+    [Q, 2m])."""
+    return 4 * m * m + 8 * n * m + 32 * join_batch * m
+
+
+def closure_build_pairs(n: int, m: int) -> int:
+    """Estimated (max, min) pair operations of the ``closure`` rows
+    build: n·m² a round, for ⌈log2 m⌉ + 1 rounds (the hops of a
+    small-world graph's longest optimal walk, plus the round that
+    changes nothing).  An n ≈ m graph costs m³ a round and fails the
+    budget where an n ≪ m one passes it."""
+    return n * m * m * (max(int(m - 1).bit_length(), 1) + 1)
+
+
+def device_memory_budget() -> int:
+    """Bytes the single-device closure route may plan for: three
+    quarters of what the default device's ``memory_stats()`` reports as
+    its limit, or ``SINGLE_DEVICE_CLOSURE_BUDGET`` where it reports
+    none."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 0))
+    return limit * 3 // 4 if limit else SINGLE_DEVICE_CLOSURE_BUDGET
+
 
 class SnapshotUnsupported(NotImplementedError):
     """Raised by backends whose structure has no padded label form."""
@@ -624,9 +668,10 @@ def workload_capabilities() -> Dict[str, Dict[str, bool]]:
 
 
 def plan_backend(h: Hypergraph, batch_hint: Optional[int] = None, *,
-                 mesh=None, device_budget_bytes: Optional[int] = None) -> str:
+                 mesh=None, device_budget_bytes: Optional[int] = None,
+                 join_batch: Optional[int] = None) -> str:
     """Pick a backend from graph size, label mass, query batch shape, and
-    (optionally) the device topology.
+    (optionally) the device topology and memory.
 
     Args:
       h: the hypergraph to serve.
@@ -636,8 +681,13 @@ def plan_backend(h: Hypergraph, batch_hint: Optional[int] = None, *,
         working set (~12·m² bytes: operand + two gathered f32 panels)
         exceeds ``device_budget_bytes``, the planner picks ``sharded``.
         A unit mesh (1 device) never routes to ``sharded``.
-      device_budget_bytes: per-device memory budget for the closure
-        working set; defaults to ``SINGLE_DEVICE_CLOSURE_BUDGET``.
+      device_budget_bytes: per-device memory budget.  The ``sharded``
+        rule defaults it to ``SINGLE_DEVICE_CLOSURE_BUDGET``; the
+        single-device ``closure`` rule to ``device_memory_budget()``
+        (three quarters of the device's reported memory).
+      join_batch: the largest query batch one join runs (the service
+        passes its ``max_batch``); defaults to ``batch_hint`` or
+        ``CLOSURE_JOIN_BATCH``, whichever is larger.
 
     Policy (documented in README.md and docs/ARCHITECTURE.md):
       * multi-device mesh + closure beyond one device -> ``sharded``
@@ -660,6 +710,12 @@ def plan_backend(h: Hypergraph, batch_hint: Optional[int] = None, *,
         exists to avoid, so the scaled budget is optimistic there
         (sub-component root-range sharding is the ROADMAP item that
         closes this);
+      * label build over budget, but the closure regime's device working
+        set (``closure_working_set_bytes``: overlap matrix, vertex rows,
+        one ``join_batch`` join) fits the device and its rows build
+        (``closure_build_pairs``) fits ``CLOSURE_BUILD_BUDGET`` ->
+        ``closure`` (dense, high-overlap graphs with n ≪ m: the rows
+        build on the device; an n ≈ m graph fails the build budget);
       * huge graphs, batched workload -> ``frontier`` (index-free sparse
         sweeps; build cost is one line-graph pass);
       * huge graphs, trickle queries -> ``online`` (no build at all).
@@ -689,6 +745,12 @@ def plan_backend(h: Hypergraph, batch_hint: Optional[int] = None, *,
     if h.nnz * max(float(h.vertex_degrees.mean()) if h.n else 0.0, 1.0) \
             <= label_budget:
         return "hl-index"
+    join = int(join_batch) if join_batch else max(q, CLOSURE_JOIN_BATCH)
+    budget = (device_memory_budget() if device_budget_bytes is None
+              else int(device_budget_bytes))
+    if (closure_working_set_bytes(h.n, h.m, join) <= budget
+            and closure_build_pairs(h.n, h.m) <= CLOSURE_BUILD_BUDGET):
+        return "closure"
     if q >= 256:
         return "frontier"
     return "online"
@@ -1150,12 +1212,15 @@ class MSTOracleEngine(_EngineBase):
 
 @register_backend("closure")
 class ClosureEngine(_EngineBase):
-    """Dense (max, min)-semiring closure W* [m, m] (semiring.py).
+    """Per-vertex rows of the dense (max, min)-semiring closure
+    (``semiring.vertex_rows``), ``R[v, e] = max_{e_v ∋ v} W*[e_v, e]``
+    [n, m], built on the device; W* itself is never formed.
 
     Its snapshot is the degenerate-but-exact label form: every hyperedge
-    is a hub, ``L(u)[e] = max_{e_u ∋ u} W*[e_u, e]``.  Bottleneck triangle
-    inequality makes the shared label join exact on these rows
-    (equality is attained at the hub e = e_u of an optimal pair).
+    is a hub, ``L(v)[e] = R[v, e]``.  Bottleneck triangle inequality
+    makes the shared label join exact on these rows (equality is
+    attained at the hub e = e_u of an optimal pair).  A scalar lookup
+    reads one row: ``MR(u, v) = max_{e_v ∋ v} R[u, e_v]``.
     """
 
     name = "closure"
@@ -1163,39 +1228,39 @@ class ClosureEngine(_EngineBase):
     workload_capability = _LABEL_OPS | _TRAVERSAL_OPS
     _gate_hop_bounded = True
 
-    def __init__(self, h: Hypergraph, w_star: np.ndarray,
-                 method: str = "maxmin"):
+    def __init__(self, h: Hypergraph, rows, rounds: Optional[int] = None):
         super().__init__(h)
-        self.w_star = w_star
-        self._method = method
+        self.rows = rows                 # [n, m] int32, device or host
+        self.rounds = rounds             # propagation rounds of the build
         self._snap: Optional[DeviceSnapshot] = None
 
     @classmethod
-    def build(cls, h: Hypergraph, *, method: str = "maxmin") -> "ClosureEngine":
+    def build(cls, h: Hypergraph) -> "ClosureEngine":
         stages = Stages("repro")
-        with stages.span("build", backend=cls.name, method=method):
-            w_star = mr_matrix(h, method=method, stages=stages)
-        eng = cls(h, w_star, method)
+        with stages.span("build", backend=cls.name):
+            rows, rounds = vertex_rows(h, stages=stages)
+        eng = cls(h, rows, rounds)
         eng.build_stages = stages
         return eng
 
     def _apply_update(self, inserts=(), deletes=()) -> None:
         # dense closures have no cheap incremental form (one new overlap
-        # can rewrite O(m²) entries); recompute whole, same protocol
+        # can rewrite O(n·m) entries); recompute whole, same protocol
         new_h, _, _ = apply_edge_edits(self.h, inserts, deletes)
-        self.w_star = mr_matrix(new_h, method=self._method)
+        self.rows = None                 # free the old rows first
         self._graph_changed(new_h)
+        self.rows, self.rounds = vertex_rows(new_h)
 
     def mr(self, u: int, v: int) -> int:
-        # scalar lookups stay on the host matrix (no reason to build the
+        # scalar lookups fetch u's row alone (no reason to build the
         # [n, m] snapshot for a trickle of queries)
         self._check_vertex_ids(u, v)
-        return int(vertex_mr_from_edge_mr(self.h, self.w_star,
-                                          [int(u)], [int(v)])[0])
+        ev = self.h.edges_of(int(v))
+        return int(np.asarray(self.rows[int(u)])[ev].max()) if ev.size else 0
 
     def mr_batch(self, us, vs) -> np.ndarray:
         # batches go through the fused device join — the reason the
-        # planner picks this backend for batched small-graph workloads
+        # planner picks this backend for batched workloads
         us, vs = validate_batch(us, vs, self.h.n)
         return np.asarray(self._query_snapshot().mr(us, vs))
 
@@ -1205,26 +1270,18 @@ class ClosureEngine(_EngineBase):
 
     def snapshot(self) -> DeviceSnapshot:
         if not self._snapshot_current():
-            h, m = self.h, self.h.m
-            svals = np.zeros((h.n, m), np.int32)
-            deg = np.diff(h.v_ptr)
-            nz = np.nonzero(deg > 0)[0]
-            if nz.size:
-                # segment-max of W* rows over each vertex's incidence list
-                # (one gather + reduceat; degree-0 vertices keep zero rows)
-                svals[nz] = np.maximum.reduceat(self.w_star[h.v_idx],
-                                                h.v_ptr[nz], axis=0)
-            ranks = np.broadcast_to(np.arange(m, dtype=np.int32), (h.n, m))
-            lengths = np.full(h.n, m, np.int32)
-            self._snap = DeviceSnapshot.from_padded(np.ascontiguousarray(ranks),
-                                                    svals, lengths, self.name,
-                                                    version=self.version)
-            self.last_snapshot_refresh_rows = h.n
+            import jax.numpy as jnp
+            n, m = self.h.n, self.h.m
+            ranks = jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32), (n, m))
+            self._snap = DeviceSnapshot.from_padded(
+                ranks, self.rows, np.full(n, m, np.int32), self.name,
+                version=self.version)
+            self.last_snapshot_refresh_rows = n
             self._dirty_rows = np.empty(0, np.int64)
         return self._snap
 
     def nbytes(self) -> int:
-        return int(self.w_star.nbytes)
+        return int(self.rows.nbytes)
 
 
 # ---------------------------------------------------------------------------

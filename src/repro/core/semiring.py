@@ -1,13 +1,23 @@
 """(max, min)-semiring formulation of max-reachability — the TPU-native
 re-expression of the paper's problem, and the oracle for all tests.
 
-Key identity (Section 2 of DESIGN.md): with ``W[i,j] = OD(e_i, e_j)``
-(diagonal ``|e_i|``), the hyperedge-level max-reachability matrix is the
-bottleneck-path closure ``W*`` under the (max, min) semiring, and
+Key identity (``docs/ARCHITECTURE.md``, "The closure regime"): with
+``W[i,j] = OD(e_i, e_j)`` (diagonal ``|e_i|``), the hyperedge-level
+max-reachability matrix is the bottleneck-path closure ``W*`` under the
+(max, min) semiring, and
 
     MR(u, v) = max_{e_u ∋ u, e_v ∋ v} W*[e_u, e_v].
 
-Two closure strategies:
+The ``closure`` backend serves the per-vertex rows of that closure,
+``R[v, e] = max_{e_v ∋ v} W*[e_v, e]`` (``vertex_rows``).  They are
+built on the device without W*: start from ``R[v, e] = |e|`` for each
+``e ∋ v`` and repeat ``R ← max(R, R ⊗ W)`` until a round changes
+nothing.  One round is an n·m² (max, min) contraction, and the rounds
+are the hops of the longest optimal walk plus one that changes nothing,
+so ``n ≪ m`` graphs never pay for the m³ squaring of W.
+
+Whole-matrix closures, kept as references for the tests and for the
+``sharded`` backend:
 
 * ``maxmin_closure`` — repeated squaring with the (max, min) matmul.
   Exact, O(log diam) rounds of an m³ VPU op (no MXU semiring support).
@@ -17,12 +27,12 @@ Two closure strategies:
   Exact when ``thresholds`` = all distinct OD values (the default).
 
 Both consume the dense line graph; the framework's scalability story for
-huge hypergraphs is the 2-D block-sharded version in ``distributed.py``.
+huge W* is the 2-D block-sharded version in ``distributed.py``.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +44,8 @@ from ..stages import Stages
 __all__ = [
     "maxmin_matmul", "maxmin_closure", "boolean_closure",
     "threshold_closure_mr", "mr_matrix", "mr_oracle_dense",
-    "vertex_mr_from_edge_mr", "distinct_thresholds",
+    "vertex_mr_from_edge_mr", "distinct_thresholds", "vertex_rows",
+    "edge_mr_rows",
 ]
 
 
@@ -165,27 +176,106 @@ def threshold_closure_mr(w: jax.Array, thresholds: Optional[np.ndarray] = None,
     return mr
 
 
-def mr_matrix(h: Hypergraph, *, method: str = "maxmin",
-              stages: Optional[Stages] = None) -> np.ndarray:
-    """Hyperedge-level MR matrix W* for a whole hypergraph.
-
-    ``stages`` receives ``build.line_graph`` (the overlap matrix, made
-    on the host and uploaded), ``build.closure`` (compiling and running
-    the closure's squaring rounds on the device, to completion) and
-    ``build.fetch`` (W* copied back to the host)."""
+def mr_matrix(h: Hypergraph) -> np.ndarray:
+    """Hyperedge-level MR matrix W* [m, m] for a whole hypergraph: the
+    dense host overlap matrix closed by squaring (a test reference; the
+    ``closure`` backend builds ``vertex_rows`` instead)."""
     if h.m == 0:                # no hyperedges: nothing is reachable
         return np.zeros((0, 0), np.int32)
-    if method not in ("maxmin", "threshold"):
-        raise ValueError(method)
+    w_star = maxmin_closure(jnp.asarray(h.line_graph(np.int32)))
+    return np.asarray(w_star).astype(np.int32, copy=False)
+
+
+def edge_mr_rows(h: Hypergraph, w_star: np.ndarray) -> np.ndarray:
+    """The vertex rows ``R[v, e] = max_{e_v ∋ v} W*[e_v, e]`` from a
+    whole closure: a segment-max of W*'s rows over each vertex's
+    incidence list (degree-0 vertices keep zero rows).  Reads W* saved
+    by older stores; ``vertex_rows`` builds the same rows without W*."""
+    w_star = np.asarray(w_star, np.int32)
+    rows = np.zeros((h.n, h.m), np.int32)
+    nz = np.nonzero(np.diff(h.v_ptr) > 0)[0]
+    if nz.size:
+        rows[nz] = np.maximum.reduceat(w_star[h.v_idx], h.v_ptr[nz], axis=0)
+    return rows
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _line_graph(members: jax.Array, sizes: jax.Array, *, n: int):
+    """The seed rows and the overlap matrix, on the device.  ``members``
+    [k, s_max] lists each hyperedge's vertices (-1 pads a short one, and
+    the rows past m, which pad the contraction); the transposed
+    incidence ``inc[e, v]`` is a compare against them, ``W = inc·incᵀ``
+    (int8 operands, int32 sums on the MXU; diagonal |e|) is [k, m], and
+    the seed is ``R[v, e] = |e|`` for each e ∋ v."""
+    m = sizes.shape[0]
+    inc = (members[:, :, None] == jnp.arange(n)[None, None, :]).any(axis=1)
+    inc = inc.astype(jnp.int8)                               # [k, n]
+    w = jax.lax.dot_general(inc, inc[:m], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.int32)
+    r0 = jnp.where(inc[:m].T > 0, sizes[None, :], 0)
+    return r0, w
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def _rows_fixpoint(r0: jax.Array, w: jax.Array, *, block: int):
+    """``R ← max(R, R ⊗ W)`` until a round changes nothing, at most m
+    rounds, in one device loop.  Returns the rows and the rounds run
+    (the last one changed nothing)."""
+    n, m = r0.shape
+    pad = w.shape[0] - m
+
+    def cond(carry):
+        _, changed, rounds = carry
+        return changed & (rounds < m)
+
+    def body(carry):
+        r, _, rounds = carry
+        prod = maxmin_matmul(jnp.pad(r, ((0, 0), (0, pad))), w, block=block)
+        r2 = jnp.maximum(r, prod)
+        return r2, jnp.any(r2 != r), rounds + 1
+
+    r, _, rounds = jax.lax.while_loop(
+        cond, body, (r0, jnp.bool_(True), jnp.int32(0)))
+    return r, rounds
+
+
+def _members(h: Hypergraph, block: int) -> np.ndarray:
+    """[k, s_max] int32: row e lists hyperedge e's vertices, -1 after
+    them and in the rows from m up to k, the next multiple of
+    ``block``."""
+    sizes = h.edge_sizes
+    k = -(-h.m // block) * block
+    out = np.full((k, max(int(sizes.max()), 1)), -1, np.int32)
+    col = np.arange(h.e_idx.size) - np.repeat(h.e_ptr[:-1], sizes)
+    out[np.repeat(np.arange(h.m), sizes), col] = h.e_idx
+    return out
+
+
+def vertex_rows(h: Hypergraph, *, stages: Optional[Stages] = None,
+                block: int = 512) -> Tuple[jax.Array, int]:
+    """The ``closure`` backend's rows ``R[v, e] = max_{e_v ∋ v} W*[e_v,
+    e]`` [n, m] int32, built on the device, and the rounds the
+    propagation ran.  Byte-identical to ``edge_mr_rows(h, mr_matrix(h))``
+    and no [m, m] array exists on the host.
+
+    ``stages`` receives ``build.line_graph`` (each hyperedge's member
+    list uploaded, the overlap matrix made from it on the device) and
+    ``build.rows`` (the propagation to its fixpoint; the span carries
+    ``n``, ``m`` and ``rounds``)."""
     stages = stages if stages is not None else Stages("repro")
-    with stages.span("build.line_graph", m=h.m):
-        w = jnp.asarray(h.line_graph(np.int32))
-    rounds = max(1, int(np.ceil(np.log2(max(h.m, 2)))))
-    with stages.span("build.closure", method=method, rounds=rounds):
-        w_star = (maxmin_closure(w) if method == "maxmin"
-                  else threshold_closure_mr(w)).block_until_ready()
-    with stages.span("build.fetch"):
-        return np.asarray(w_star).astype(np.int32, copy=False)
+    n, m = h.n, h.m
+    if m == 0:                  # no hyperedges: nothing is reachable
+        return jnp.zeros((n, 0), jnp.int32), 0
+    with stages.span("build.line_graph", n=n, m=m):
+        r0, w = _line_graph(jnp.asarray(_members(h, block)),
+                            jnp.asarray(h.edge_sizes, jnp.int32), n=n)
+        w.block_until_ready()
+    with stages.span("build.rows", n=n, m=m) as span:
+        rows, rounds = _rows_fixpoint(r0, w, block=block)
+        del r0, w
+        rounds = int(rounds)            # waits for the loop to end
+        span.set(rounds=rounds)
+    return rows, rounds
 
 
 def vertex_mr_from_edge_mr(h: Hypergraph, w_star: np.ndarray,

@@ -47,6 +47,7 @@ from ..core.hlindex import HLIndex, build_basic, build_fast, build_sharded
 from ..core.hypergraph import Hypergraph, NeighborCSR
 from ..core.minimal import minimize
 from ..core.query import DeviceSnapshot
+from ..core.semiring import edge_mr_rows
 
 __all__ = [
     "FORMAT_VERSION", "FORMAT_REGISTRY", "MAGIC",
@@ -254,7 +255,9 @@ def save_index(path, engine, *, neighbors: Optional[NeighborCSR] = None) -> Dict
 
     * ``hl-index`` / ``hl-index-basic`` — rank, perm, label and dual
       lists as ragged (ptr, values) segments (payload ``labels``);
-    * ``closure`` — the dense W* matrix (payload ``closure``);
+    * ``closure`` — the vertex rows ``[n, m]`` (payload ``closure``,
+      segment ``rows``; stores written before hold W* as ``w_star``,
+      and load through ``edge_mr_rows``);
     * ``sharded`` — the label regime saves its HL-index; the closure
       regime saves the gathered, mesh-padding-trimmed W*; after
       ``snapshot()`` freed the closure, the padded snapshot tensors are
@@ -286,8 +289,8 @@ def save_index(path, engine, *, neighbors: Optional[NeighborCSR] = None) -> Dict
         segments += _hlindex_segments(engine.idx)
     elif name == "closure":
         meta["payload"] = "closure"
-        meta["engine_opts"] = {"method": engine._method}
-        segments.append(("w_star", np.asarray(engine.w_star)))
+        meta["engine_opts"] = {}
+        segments.append(("rows", np.ascontiguousarray(engine.rows)))
     else:                                              # sharded
         meta["engine_opts"] = {
             "schedule": engine.schedule, "axes": list(engine.axes),
@@ -448,8 +451,11 @@ def load_index(path, *, mesh=None, verify: bool = True,
     opts = manifest.get("engine_opts", {})
     version = int(manifest["engine_version"])
     if backend == "closure":
-        eng = ClosureEngine(h, seg["w_star"],
-                            method=opts.get("method", "maxmin"))
+        # the vertex rows; a store written before they were saved holds
+        # the whole closure W*, from which the rows follow
+        rows = (seg["rows"] if "rows" in seg
+                else edge_mr_rows(h, seg["w_star"]))
+        eng = ClosureEngine(h, rows)
     else:
         cls = HLIndexEngine if backend == "hl-index" else HLIndexBasicEngine
         idx = _load_hlindex(h, manifest, seg)

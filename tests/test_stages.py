@@ -143,10 +143,11 @@ def test_closure_build_records_its_stages():
     h = random_hypergraph(40, 60, seed=4)
     eng = build_engine(h, "closure")
     tot = eng.build_stages.totals()
-    assert set(tot) == {"build", "build.line_graph", "build.closure",
-                        "build.fetch"}
-    assert sum(tot[k].wall_s for k in ("build.line_graph", "build.closure",
-                                       "build.fetch")) <= tot["build"].wall_s
+    assert set(tot) == {"build", "build.line_graph", "build.rows"}
+    assert all(tot[k].count == 1 for k in tot)
+    assert sum(tot[k].wall_s for k in ("build.line_graph", "build.rows")
+               ) <= tot["build"].wall_s
+    assert 1 <= eng.rounds <= h.m
 
 
 def test_engines_without_stages_expose_empty_totals():

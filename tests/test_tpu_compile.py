@@ -85,6 +85,7 @@ def test_batched_mr_compiles(one_chip):
 
 @pytest.mark.parametrize("n, width, q, temp_bound", [
     (327, 7818, 4096, 3 * 2**30),      # closure rows, a full 4,096 bucket
+    (998, 25_800, 4096, 4 * 2**30),    # email-eu's closure rows
     (11_107, 588, 64, 64 * 2**20),     # HL-index rows, an open-loop bucket
 ])
 def test_batched_mr_compiles_loop_free(one_chip, n, width, q, temp_bound):
@@ -95,6 +96,22 @@ def test_batched_mr_compiles_loop_free(one_chip, n, width, q, temp_bound):
     compiled = batched_mr.lower(labels, labels, queries, queries).compile()
     assert "while" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
+
+
+def test_closure_rows_build_compiles(one_chip):
+    # email-eu (998 x 25,800): the overlap matrix from the members on the
+    # MXU, then the propagation loop, with no [n, block, m] temporary
+    from repro.core.semiring import _line_graph, _rows_fixpoint
+    n, m, k = 998, 25_800, 26_112
+    line = _line_graph.lower(_spec((k, 25), jnp.int32, one_chip),
+                             _spec((m,), jnp.int32, one_chip),
+                             n=n).compile()
+    assert line.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    rows = _rows_fixpoint.lower(_spec((n, m), jnp.int32, one_chip),
+                                _spec((k, m), jnp.int32, one_chip),
+                                block=512).compile()
+    assert "while" in rows.as_text()
+    assert rows.memory_analysis().temp_size_in_bytes < 512 * 2**20
 
 
 @pytest.mark.parametrize("schedule", ["allgather", "ring"])
