@@ -9,7 +9,11 @@ dense tensors (``HLIndex.as_padded``), queries answered by a sort-merge
 join — each query's two label rows are gathered whole and merged by one
 sort of their concatenation, with no per-element gather, no loop and no
 host pointer chasing, and a [Q]-sized batch is one XLA program.  This is the engine the paper's Exp-1 (1,000-query workload)
-maps onto; it serves millions of queries per batch.
+maps onto; it serves millions of queries per batch.  Where every row
+holds hub j in column j (the closures' dense rows), ``batched_mr_aligned``
+answers the same join with an elementwise min and a row max instead;
+``DeviceSnapshot.join_form`` picks between the two from the snapshot's
+data.
 """
 from __future__ import annotations
 
@@ -24,7 +28,8 @@ import numpy as np
 from .hlindex import HLIndex
 
 __all__ = ["mr_query", "s_reach_query", "mr_query_dicts", "DeviceSnapshot",
-           "KernelSnapshot", "PaddedIndex", "batched_mr"]
+           "KernelSnapshot", "PaddedIndex", "batched_mr",
+           "batched_mr_aligned"]
 
 
 def mr_query(idx: HLIndex, u: int, v: int) -> int:
@@ -101,7 +106,8 @@ def _mesh_row_scatter(spec2d, spec1d, donate: bool):
 
 @dataclasses.dataclass(eq=False)    # identity equality/hash: fields are arrays
 class DeviceSnapshot:
-    """Padded per-vertex label tensors on device, served by ``batched_mr``.
+    """Padded per-vertex label tensors on device, served by ``batched_mr``
+    or, for key-aligned rows, ``batched_mr_aligned`` (``join_form``).
 
     Tensor layout and sentinel conventions:
 
@@ -287,11 +293,26 @@ class DeviceSnapshot:
         return int(self.ranks.nbytes + self.svals.nbytes
                    + self.lengths.nbytes)
 
+    @functools.cached_property
+    def join_form(self) -> str:
+        """Which join answers this snapshot, decided once from its data:
+        ``"aligned"`` when every column j holds key j or carries s = 0
+        (the closures' rows, padded or grown by any sentinel columns),
+        so a common hub always sits in the same column of both rows and
+        ``batched_mr_aligned`` needs no keys; ``"merge"`` otherwise
+        (sparse HL-index / ETE labels), answered by ``batched_mr``.
+        Computed on the first join (one device reduction, one scalar
+        fetch) and kept for the snapshot's life."""
+        return "aligned" if bool(_key_aligned(self.ranks, self.svals)) \
+            else "merge"
+
     def mr(self, us, vs) -> jnp.ndarray:
         us = jnp.asarray(us)
         if self.lmax == 0:          # no labels anywhere: nothing is reachable
             return jnp.zeros(us.shape, jnp.int32)
-        return batched_mr(self.ranks, self.svals, us, jnp.asarray(vs))
+        # key-aligned rows need no keys: batched_mr takes the aligned form
+        ranks = None if self.join_form == "aligned" else self.ranks
+        return batched_mr(ranks, self.svals, us, jnp.asarray(vs))
 
     def s_reach(self, us, vs, s: int) -> jnp.ndarray:
         return self.mr(us, vs) >= s
@@ -406,12 +427,15 @@ class PaddedIndex(DeviceSnapshot):
 
 
 @functools.partial(jax.jit, donate_argnums=())
-def batched_mr(ranks: jax.Array, svals: jax.Array,
+def batched_mr(ranks: Optional[jax.Array], svals: jax.Array,
                us: jax.Array, vs: jax.Array) -> jax.Array:
     """MR(u, v) for a batch of query pairs.
 
-    Both label rows of each query are gathered whole (row gathers, which
-    the chip does as DMAs) and concatenated; one sort of each [2 Lmax]
+    ``ranks=None`` says the rows are key-aligned (column j holds hub j or
+    s = 0; ``DeviceSnapshot.join_form``) and answers by
+    ``batched_mr_aligned``, which reads no keys.  Otherwise both label
+    rows of each query are gathered whole (row gathers, which the chip
+    does as DMAs) and concatenated; one sort of each [2 Lmax]
     row by hub key, carrying s along, lines a hub common to u and v up
     in two adjacent slots, since a key appears at most once per row.
     The answer is the largest min(s_u, s_v) over equal adjacent keys.
@@ -420,8 +444,32 @@ def batched_mr(ranks: jax.Array, svals: jax.Array,
     per-element gather and no loop; the cost per query depends only on
     the row width.
     """
+    if ranks is None:
+        return batched_mr_aligned(svals, us, vs)
     keys = jnp.concatenate([ranks[us], ranks[vs]], axis=1)      # [Q, 2L]
     s = jnp.concatenate([svals[us], svals[vs]], axis=1)
     keys, s = jax.lax.sort((keys, s), dimension=1, num_keys=1)
     hit = keys[:, 1:] == keys[:, :-1]
     return jnp.where(hit, jnp.minimum(s[:, 1:], s[:, :-1]), 0).max(axis=1)
+
+
+@jax.jit
+def _key_aligned(ranks: jax.Array, svals: jax.Array) -> jax.Array:
+    """True when every slot with s > 0 sits in the column its key names."""
+    cols = jnp.arange(ranks.shape[1], dtype=ranks.dtype)
+    return jnp.all((ranks == cols[None, :]) | (svals == 0))
+
+
+@functools.partial(jax.jit, donate_argnums=())
+def batched_mr_aligned(svals: jax.Array, us: jax.Array,
+                       vs: jax.Array) -> jax.Array:
+    """MR(u, v) for a batch of query pairs over key-aligned rows.
+
+    Where column j holds hub j in every row (or s = 0), a hub common to
+    u and v sits in the same column of both rows, so the join is
+    max_j min(s_u[j], s_v[j]): two row gathers, an elementwise min and
+    a row max, with no keys, no sort and no data-dependent work.  Exact,
+    and equal to the sort-merge on such rows; ``DeviceSnapshot.join_form``
+    decides when it applies, and ``batched_mr(None, ...)`` calls it.
+    """
+    return jnp.minimum(svals[us], svals[vs]).max(axis=1)

@@ -302,6 +302,8 @@ class ServiceStats:
     rows_full: int = 0               # rows a from-scratch refresh would cost
     mesh_rows_patched: int = 0       # rows re-landed into a mesh-resident copy
     kernel_batches: int = 0          # batches answered by the Pallas join
+    join_form_groups: Dict[str, int] = dataclasses.field(
+        default_factory=dict)        # groups by join form (_join_form)
     workload_answered: Dict[str, int] = dataclasses.field(
         default_factory=dict)        # per-kind workload answers served
     updates: int = 0
@@ -313,7 +315,7 @@ class ServiceStats:
         d = dataclasses.asdict(self)
         for key in ("bucket_histogram", "tenant_submitted",
                     "tenant_answered", "tenant_expired",
-                    "workload_answered", "stages"):
+                    "workload_answered", "join_form_groups", "stages"):
             d[key] = dict(sorted(d[key].items()))
         return d
 
@@ -328,6 +330,17 @@ def _resolve(fut: Future, value) -> None:
         fut.set_result(value)
     except InvalidStateError:
         pass                         # cancelled mid-dispatch: drop quietly
+
+
+def _join_form(snap) -> str:
+    """The join a group is answered by: the snapshot's own form
+    (``DeviceSnapshot.join_form``: ``"aligned"`` or ``"merge"``),
+    ``"kernel"`` through the Pallas view, ``"engine"`` with no snapshot."""
+    if snap is None:
+        return "engine"
+    if isinstance(snap, KernelSnapshot):
+        return "kernel"
+    return snap.join_form
 
 
 def _bucket_size(q: int, min_bucket: int, max_batch: int) -> int:
@@ -692,6 +705,7 @@ class ReachabilityService:
                 tenant_answered=dict(self._stats.tenant_answered),
                 tenant_expired=dict(self._stats.tenant_expired),
                 workload_answered=dict(self._stats.workload_answered),
+                join_form_groups=dict(self._stats.join_form_groups),
                 stages=self._stages.totals())
 
     def pending(self) -> int:
@@ -837,9 +851,12 @@ class ReachabilityService:
                 self._stats.bucket_histogram.get(bucket, 0) + 1
             if isinstance(snap, KernelSnapshot):
                 self._stats.kernel_batches += 1
+            form = _join_form(snap)
+            self._stats.join_form_groups[form] = \
+                self._stats.join_form_groups.get(form, 0) + 1
 
         if kind == "mr":
-            with stages.span("join", bucket=bucket):
+            with stages.span("join", bucket=bucket, form=form):
                 if snap is not None:
                     mr = np.asarray(snap.mr(us, vs))[:q]
                 else:
@@ -849,7 +866,7 @@ class ReachabilityService:
                     _resolve(entry.future, int(val))
             return
 
-        with stages.span("join", bucket=bucket):
+        with stages.span("join", bucket=bucket, form=form):
             if snap is not None:
                 # one fused join answers every s at once: s_reach == mr >= s
                 ok = np.asarray(snap.mr(us, vs))[:q] >= svals

@@ -1,11 +1,13 @@
-"""Query kernels: the Pallas label-join and the XLA ``batched_mr`` join
-vs the merge-join reference.
+"""Query kernels: the Pallas label-join and the XLA joins (``batched_mr``
+and, on key-aligned rows, ``batched_mr_aligned``) vs the merge-join
+reference.
 
 The padded-engine-vs-oracle equivalence checks that used to live here
 are conformance matrix cells now (tests/test_conformance.py: the
 ``snapshot`` operation and the PaddedIndex back-compat test); this file
 keeps the kernel-specific coverage.
 """
+import json
 import types
 
 import jax
@@ -13,10 +15,14 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro.api import MRRequest, SReachRequest, serve
 from repro.core import (random_hypergraph, build_fast, minimize,
                         PaddedIndex, mr_oracle_dense, batched_mr, mr_query)
 from repro.core.hlindex import pad_label_rows
+from repro.core.query import DeviceSnapshot, batched_mr_aligned
 from repro.kernels import label_join, MAX_RANK
+
+from util_subproc import run_with_devices
 
 
 @pytest.fixture(scope="module")
@@ -67,7 +73,7 @@ def _searchsorted_mr(ranks, svals, us, vs):
 def _label_rows(case, rng):
     """Ragged (ascending keys, s) label rows for one join case."""
     n = 24
-    if case == "closure_dense":      # every hyperedge a hub, every row full
+    if case.startswith("closure"):   # every hyperedge a hub, every row full
         m = 45
         return ([np.arange(m, dtype=np.int32) for _ in range(n)],
                 [rng.integers(0, 6, m).astype(np.int32) for _ in range(n)])
@@ -92,15 +98,55 @@ def _label_rows(case, rng):
     return ranks, svals
 
 
+_GROWN = 64                                         # closure_patched width
+
+# the closure rows re-landed on a 2 x 2 mesh: 45 columns pad to 46
+_MESH_JOIN = """
+import json
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.core.query import DeviceSnapshot, batched_mr
+ranks, svals, lengths, us, vs = (np.array(a, np.int32) for a in json.loads(%r))
+mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
+snap = DeviceSnapshot.from_padded(ranks, svals, lengths, "test").to_mesh(mesh)
+assert snap.ranks.shape == (24, 46), snap.ranks.shape
+print(json.dumps({"form": snap.join_form,
+                  "picked": np.asarray(snap.mr(us, vs)).tolist(),
+                  "merge": np.asarray(batched_mr(snap.ranks, snap.svals,
+                                                 us, vs)).tolist()}))
+"""
+
+
+def _snapshot(case, rng):
+    """The case's ``DeviceSnapshot`` and its ragged (keys, s) rows."""
+    row_ranks, row_svals = _label_rows(case, rng)
+    ranks, svals, lengths = pad_label_rows(row_ranks, row_svals)
+    snap = DeviceSnapshot.from_padded(ranks, svals, lengths, "test")
+    if case == "closure_patched":
+        # the sharded closure's scoped refresh: dirty rows re-derived at a
+        # grown width, untouched rows padded with INT32_MAX keys and s = 0
+        dirty = np.arange(1, len(row_ranks), 3)
+        keys = np.broadcast_to(np.arange(_GROWN, dtype=np.int32),
+                               (dirty.size, _GROWN))
+        s = rng.integers(0, 6, (dirty.size, _GROWN)).astype(np.int32)
+        snap = snap.patch_rows(dirty, keys, s,
+                               np.full(dirty.size, _GROWN, np.int32),
+                               lmax=_GROWN)
+        for i, u in enumerate(dirty):
+            row_ranks[u], row_svals[u] = keys[i].copy(), s[i]
+    return snap, row_ranks, row_svals
+
+
 @pytest.mark.parametrize("bucket", [8, 64])
 @pytest.mark.parametrize("case", ["lmax1", "width37", "width588",
                                   "closure_dense", "all_padding_rows",
-                                  "u_equals_v", "max_rank_keys"])
+                                  "u_equals_v", "max_rank_keys",
+                                  "closure_patched", "closure_mesh"])
 def test_batched_mr_exact(case, bucket):
-    # the sort-merge join against Algorithm 5 and the searchsorted join
+    # both join forms against Algorithm 5 and the searchsorted join; the
+    # snapshot takes the aligned form exactly on the closures' rows
     rng = np.random.default_rng([bucket, len(case)])
-    row_ranks, row_svals = _label_rows(case, rng)
-    ranks, svals, _ = pad_label_rows(row_ranks, row_svals)
+    snap, row_ranks, row_svals = _snapshot(case, rng)
     idx = types.SimpleNamespace(labels_rank=row_ranks, labels_s=row_svals)
     n = len(row_ranks)
     us = rng.integers(0, n, bucket).astype(np.int32)
@@ -110,9 +156,53 @@ def test_batched_mr_exact(case, bucket):
         vs = us.copy()
     if case == "all_padding_rows":
         us[4:8], vs[4:8] = 2, np.array([4, 0, 1, 6])   # empty vs empty/full
-    got = np.asarray(batched_mr(jnp.asarray(ranks), jnp.asarray(svals),
-                                jnp.asarray(us), jnp.asarray(vs)))
     want = np.array([mr_query(idx, u, v) for u, v in zip(us, vs)])
-    np.testing.assert_array_equal(got, want)
+    ranks, svals = np.asarray(snap.ranks), np.asarray(snap.svals)
     np.testing.assert_array_equal(
-        got, np.asarray(_searchsorted_mr(ranks, svals, us, vs)))
+        want, np.asarray(_searchsorted_mr(ranks, svals, us, vs)))
+    if case == "closure_mesh":
+        out = json.loads(run_with_devices(_MESH_JOIN % json.dumps(
+            [a.tolist() for a in (ranks, svals, np.asarray(snap.lengths),
+                                  us, vs)])).strip().splitlines()[-1])
+        assert out["form"] == "aligned"
+        np.testing.assert_array_equal(out["picked"], want)
+        np.testing.assert_array_equal(out["merge"], want)
+        return
+    aligned = case.startswith("closure")
+    assert snap.join_form == ("aligned" if aligned else "merge")
+    if case == "closure_patched":
+        assert ranks.shape == (n, _GROWN)
+        assert (ranks[0, 45:] == np.iinfo(np.int32).max).all()
+    np.testing.assert_array_equal(np.asarray(snap.mr(us, vs)), want)
+    np.testing.assert_array_equal(
+        np.asarray(batched_mr(jnp.asarray(ranks), jnp.asarray(svals),
+                              jnp.asarray(us), jnp.asarray(vs))), want)
+    if aligned:
+        np.testing.assert_array_equal(
+            np.asarray(batched_mr_aligned(jnp.asarray(svals), jnp.asarray(us),
+                                          jnp.asarray(vs))), want)
+
+
+@pytest.mark.parametrize("backend, aligned", [("closure", True),
+                                              ("hl-index", False)])
+def test_service_counts_groups_by_join_form(backend, aligned):
+    # every group of a closure engine takes the aligned join; none of an
+    # HL-index engine's does
+    h = random_hypergraph(40, 60, seed=9)
+    oracle = mr_oracle_dense(h)
+    svc = serve(h, backend, start=False)
+    rng = np.random.default_rng(4)
+    pairs = rng.integers(0, h.n, (3, 2, 50))
+    for us, vs in pairs:
+        reqs = [MRRequest(int(u), int(v)) for u, v in zip(us, vs)]
+        reqs += [SReachRequest(int(u), int(v), 2) for u, v in zip(us, vs)]
+        futs = svc.submit_many(reqs)
+        svc.drain()
+        assert [f.result(timeout=0) for f in futs] == (
+            [int(oracle[u, v]) for u, v in zip(us, vs)]
+            + [bool(oracle[u, v] >= 2) for u, v in zip(us, vs)])
+    st = svc.stats()
+    assert sum(st.join_form_groups.values()) == st.batches >= 2
+    assert st.join_form_groups.get("aligned", 0) == (st.batches if aligned
+                                                     else 0)
+    assert st.as_dict()["join_form_groups"] == st.join_form_groups

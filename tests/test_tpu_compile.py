@@ -98,6 +98,25 @@ def test_batched_mr_compiles_loop_free(one_chip, n, width, q, temp_bound):
     assert compiled.memory_analysis().temp_size_in_bytes < temp_bound
 
 
+@pytest.mark.parametrize("n, width", [(327, 7818), (998, 25_800)])
+def test_batched_mr_aligned_compiles(one_chip, n, width):
+    # the closures' join as served, batched_mr with no keys: gathers, a
+    # min and a row max; no loop, no sort, and no more temporaries than
+    # the two gathered [Q, m] row blocks at the chip's tiled width (m
+    # padded to 128 lanes).  The benchmark's trace reader finds join
+    # programs by "batched_mr".
+    q, lanes = 4096, -(-width // 128) * 128
+    svals = _spec((n, width), jnp.int32, one_chip)
+    queries = _spec((q,), jnp.int32, one_chip)
+    lowered = batched_mr.lower(None, svals, queries, queries)
+    assert "batched_mr" in lowered.as_text().splitlines()[0]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "while" not in text and "sort" not in text
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 2 * q * lanes * 4 + 2**20)
+
+
 def test_closure_rows_build_compiles(one_chip):
     # email-eu (998 x 25,800): the overlap matrix from the members on the
     # MXU, then the propagation loop, with no [n, block, m] temporary
