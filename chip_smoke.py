@@ -45,8 +45,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 SRC = ROOT / "src"
 
-# walmart-trips (Benson et al.) at its published scale; benchmarks/
-# datasets.py keeps a CPU-sized "WA-s" stand-in of the same shape
+# walmart-trips (Benson et al.) at its published scale
 WALMART = dict(n=88_860, m=69_906, min_size=2, max_size=8)
 CLOSURE_M = 8192          # phase (c): W* f32 [8192, 8192] = 256 MiB
 MESH_M = 16_384           # --chips 4: W* 1 GiB, 256 MiB per chip

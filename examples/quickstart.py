@@ -58,7 +58,6 @@ def main():
     # --- live updates: scoped maintenance through the same engine ---------
     # construction reruns only on the affected line-graph component, so
     # on a multi-component graph updates cost ~1/C of a rebuild
-    # (benchmarks/bench_maintenance.py tracks this as BENCH_maintenance.json)
     from repro.api import planted_chain_hypergraph
     hc = planted_chain_hypergraph(16, 40, overlap=3, extra_size=2, seed=0)
     t0 = time.perf_counter()

@@ -247,9 +247,9 @@ def sharded_threshold_closure_mr(w, thresholds, mesh: Mesh, *,
 
 
 def collective_bytes_of(lowered_text: str) -> dict:
-    """Sum operand bytes of collectives in an HLO dump — shared helper for
-    the roofline harness (single source of truth lives here so both the
-    reachability benches and the LM dry-run use identical accounting)."""
+    """Sum operand bytes of collectives in an HLO dump: the sharded
+    closure's collective accounting (``examples/distributed_reachability.py``
+    reports it per schedule)."""
     import re
     ops = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
            "collective-permute")

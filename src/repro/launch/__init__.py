@@ -1,11 +1,10 @@
-"""Launchers: mesh construction, dry-run, training, serving.
+"""Launchers: mesh construction, HLO collective analysis, and the
+production-scale dry-run of the sharded closure.
 
-NOTE: ``dryrun`` must be imported/run as the process entry point (it sets
+NOTE: ``closure_dryrun`` must be run as the process entry point (it sets
 XLA_FLAGS before jax initializes); do not import it from an
 already-initialized process and expect 512 devices.
 """
 from .mesh import make_production_mesh, make_test_mesh
-from .shapes import SHAPES, ShapeCell, cell_applicable, all_cells
 
-__all__ = ["make_production_mesh", "make_test_mesh", "SHAPES", "ShapeCell",
-           "cell_applicable", "all_cells"]
+__all__ = ["make_production_mesh", "make_test_mesh"]

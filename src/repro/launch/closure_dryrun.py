@@ -13,8 +13,6 @@ Cells (one squaring round each; a full closure is ⌈log2 m⌉ rounds):
   * bisection ladder   log2(S)=5 effective thresholds — the beyond-paper
     optimization (see EXPERIMENTS.md §Perf).
 
-Records the same fields as the LM dry-run so §Roofline reads both.
-
   PYTHONPATH=src python -m repro.launch.closure_dryrun --out results/dryrun_core
 """
 import argparse

@@ -17,8 +17,7 @@ __all__ = ["make_production_mesh", "make_test_mesh"]
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """Single pod: 16×16 = 256 chips, axes (data, model).
     Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model) — the ``pod``
-    axis carries only DP gradient all-reduce (training) or the threshold
-    batch (reachability engine) across the inter-pod DCI."""
+    axis carries only the threshold batch across the inter-pod DCI."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _make_mesh(shape, axes)

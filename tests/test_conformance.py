@@ -26,7 +26,8 @@ from repro.api import (ServiceConfig, available_backends, build_engine,
                        from_edge_lists)
 from repro.store import load_index, save_index
 from repro.core import (MSTOracle, PaddedIndex, apply_edge_edits, build_fast,
-                        minimize, brute_force_mr_from_set, brute_force_mr_set,
+                        minimize, mr_oracle_dense,
+                        brute_force_mr_from_set, brute_force_mr_set,
                         brute_force_s_distance, brute_force_s_reach_k,
                         brute_force_top_s)
 from repro.core.engine import (SnapshotUnsupported, UpdateUnsupported,
@@ -357,16 +358,23 @@ def test_op_s_distance(case, config):
 # backend (moved here from test_serving.py)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("config", CONFIG_NAMES)
-def test_service_matches_oracle(config):
+def _service(h, config):
+    """A stopped service over one matrix row's engine."""
     backend, opts = CONFIGS[config]
-    h = random_hypergraph(30, 45, seed=3)
     if opts.get("_restore"):
-        svc = serve(_build(h, config), start=False)
-    else:
-        opts = dict(opts)
-        svc_cfg = ServiceConfig(use_kernels=opts.pop("use_kernels", None))
-        svc = serve(h, backend, start=False, config=svc_cfg, **opts)
+        return serve(_build(h, config), start=False)
+    opts = dict(opts)
+    svc_cfg = ServiceConfig(use_kernels=opts.pop("use_kernels", None))
+    return serve(h, backend, start=False, config=svc_cfg, **opts)
+
+
+@pytest.mark.parametrize("traffic", ["mixed_s", "uniform_s"])
+@pytest.mark.parametrize("config", CONFIG_NAMES)
+def test_service_matches_oracle(config, traffic):
+    # uniform_s: every s-reach request carries one s, so a snapshot-less
+    # backend answers the group through its own s_reach_batch
+    h = random_hypergraph(30, 45, seed=3)
+    svc = _service(h, config)
     oracle = MSTOracle(h)
     rng = np.random.default_rng(7)
     reqs, want = [], []
@@ -377,7 +385,7 @@ def test_service_matches_oracle(config):
             reqs.append(MRRequest(u, v))
             want.append(mr)
         else:
-            s = int(rng.integers(1, 5))
+            s = 2 if traffic == "uniform_s" else int(rng.integers(1, 5))
             reqs.append(SReachRequest(u, v, s))
             want.append(mr >= s)
     futs = svc.submit_many(reqs)
@@ -388,6 +396,42 @@ def test_service_matches_oracle(config):
         got = fut.result(timeout=0)
         assert got == w, (req, got, w)
         assert isinstance(got, int if req.kind == "mr" else bool)
+
+
+# the join each matrix row's service answers a group by
+# (reach_service._join_form)
+EXPECTED_JOIN_FORM = {
+    "closure": "aligned", "sharded": "aligned",
+    "sharded[restored]": "aligned",
+    "hl-index": "merge", "hl-index-basic": "merge", "ete": "merge",
+    "hl-index[restored]": "merge", "hl-index[sharded-build]": "merge",
+    "sharded[labels]": "merge",
+    "hl-index[kernels]": "kernel", "sharded[kernels]": "kernel",
+    "online": "engine", "frontier": "engine", "threshold": "engine",
+    "mst-oracle": "engine",
+}
+
+
+@pytest.mark.parametrize("config", CONFIG_NAMES)
+def test_service_counts_groups_by_join_form(config):
+    # every group of a row's service takes the join its config implies
+    h = random_hypergraph(40, 60, seed=9)
+    oracle = mr_oracle_dense(h)
+    svc = _service(h, config)
+    rng = np.random.default_rng(4)
+    pairs = rng.integers(0, h.n, (3, 2, 50))
+    for us, vs in pairs:
+        reqs = [MRRequest(int(u), int(v)) for u, v in zip(us, vs)]
+        reqs += [SReachRequest(int(u), int(v), 2) for u, v in zip(us, vs)]
+        futs = svc.submit_many(reqs)
+        svc.drain()
+        assert [f.result(timeout=0) for f in futs] == (
+            [int(oracle[u, v]) for u, v in zip(us, vs)]
+            + [bool(oracle[u, v] >= 2) for u, v in zip(us, vs)])
+    st = svc.stats()
+    assert st.batches >= 2
+    assert st.join_form_groups == {EXPECTED_JOIN_FORM[config]: st.batches}
+    assert st.as_dict()["join_form_groups"] == st.join_form_groups
 
 
 # ---------------------------------------------------------------------------

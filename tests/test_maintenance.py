@@ -51,8 +51,7 @@ def test_insert_scope_is_component_local():
 
 def test_construction_is_scoped():
     # the *construction* input is the extracted sub-hypergraph, not the
-    # full graph — the PR's tentpole claim, asserted on the stats the
-    # splice records (and benchmarked in benchmarks/bench_maintenance.py)
+    # full graph, asserted on the stats the splice records
     h = planted_chain_hypergraph(4, 8, overlap=2, extra_size=2, seed=1)
     idx = build_fast(h)
     v0 = int(h.edge(0)[0])

@@ -15,7 +15,6 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.api import MRRequest, SReachRequest, serve
 from repro.core import (random_hypergraph, build_fast, minimize,
                         PaddedIndex, mr_oracle_dense, batched_mr, mr_query)
 from repro.core.hlindex import pad_label_rows
@@ -181,28 +180,3 @@ def test_batched_mr_exact(case, bucket):
         np.testing.assert_array_equal(
             np.asarray(batched_mr_aligned(jnp.asarray(svals), jnp.asarray(us),
                                           jnp.asarray(vs))), want)
-
-
-@pytest.mark.parametrize("backend, aligned", [("closure", True),
-                                              ("hl-index", False)])
-def test_service_counts_groups_by_join_form(backend, aligned):
-    # every group of a closure engine takes the aligned join; none of an
-    # HL-index engine's does
-    h = random_hypergraph(40, 60, seed=9)
-    oracle = mr_oracle_dense(h)
-    svc = serve(h, backend, start=False)
-    rng = np.random.default_rng(4)
-    pairs = rng.integers(0, h.n, (3, 2, 50))
-    for us, vs in pairs:
-        reqs = [MRRequest(int(u), int(v)) for u, v in zip(us, vs)]
-        reqs += [SReachRequest(int(u), int(v), 2) for u, v in zip(us, vs)]
-        futs = svc.submit_many(reqs)
-        svc.drain()
-        assert [f.result(timeout=0) for f in futs] == (
-            [int(oracle[u, v]) for u, v in zip(us, vs)]
-            + [bool(oracle[u, v] >= 2) for u, v in zip(us, vs)])
-    st = svc.stats()
-    assert sum(st.join_form_groups.values()) == st.batches >= 2
-    assert st.join_form_groups.get("aligned", 0) == (st.batches if aligned
-                                                     else 0)
-    assert st.as_dict()["join_form_groups"] == st.join_form_groups

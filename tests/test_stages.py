@@ -7,6 +7,7 @@ import time
 
 import pytest
 
+from repro import stages
 from repro.api import build_engine, random_hypergraph
 from repro.stages import Stages, StageTotal
 
@@ -24,12 +25,25 @@ def test_span_counts_runs_and_times_wall_above_cpu():
     assert 0 <= t.cpu_share < 0.5
 
 
-def test_busy_span_cpu_time_tracks_its_wall_time():
+def test_busy_span_cpu_time_tracks_its_wall_time(monkeypatch):
+    # the span reads fake clocks: a 50 ms spin in 1 ms steps, off the
+    # CPU for every fifth step as a loaded machine would take it off,
+    # and each clock read costs both clocks 1 us
+    clock = {"wall": 0, "cpu": 0}
+
+    def read(name):
+        clock["wall"] += 1_000
+        clock["cpu"] += 1_000
+        return clock[name]
+
+    monkeypatch.setattr(stages, "_wall_ns", lambda: read("wall"))
+    monkeypatch.setattr(stages, "_cpu_ns", lambda: read("cpu"))
     st = Stages("repro.test")
     with st.span("spin"):
-        end = time.perf_counter() + 0.05
-        while time.perf_counter() < end:
-            pass
+        for step in range(50):
+            clock["wall"] += 1_000_000
+            if step % 5:
+                clock["cpu"] += 1_000_000
     t = st.totals()["spin"]
     assert t.wall_s >= 0.05
     assert t.cpu_s <= t.cpu_wall_s + 1e-3

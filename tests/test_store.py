@@ -478,22 +478,15 @@ def test_hif_rejects_directed_and_garbage(tmp_path):
 
 
 def test_hif_through_make_dataset(tmp_path):
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "benchmarks"))
-    try:
-        from datasets import make_dataset
-    finally:
-        sys.path.pop(0)
     h = random_hypergraph(20, 25, seed=9)
     p = tmp_path / "ds.hif.json"
     write_hif(p, h)
-    h2 = make_dataset(str(p))
+    h2 = read_hif(str(p))
     assert h2.n == h.n and h2.m == h.m
     for f in ("e_ptr", "e_idx", "v_ptr", "v_idx"):
         assert np.array_equal(getattr(h, f), getattr(h2, f))
     with pytest.raises(FileNotFoundError):
-        make_dataset(str(tmp_path / "missing.hif.json"))
+        read_hif(str(tmp_path / "missing.hif.json"))
     # an engine built from the imported graph answers like the original
     a = build_engine(h, "hl-index")
     b = build_engine(h2, "hl-index")

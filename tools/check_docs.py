@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""Docs consistency guard (run by the CI `docs` job).
+"""Docs consistency guard (run by the CI `docs` job and by
+tests/test_docs.py, one case per check in ``CHECKS``).
 
 Nine checks, so documentation cannot silently drift from the code:
 
@@ -374,15 +375,14 @@ def check_workload_table():
     return problems
 
 
+CHECKS = (check_links, check_backend_table, check_update_capability_table,
+          check_request_type_table, check_construction_table,
+          check_format_table, check_kernel_table, check_multitenant_section,
+          check_workload_table)
+
+
 def main() -> int:
-    problems = (check_links() + check_backend_table()
-                + check_update_capability_table()
-                + check_request_type_table()
-                + check_construction_table()
-                + check_format_table()
-                + check_kernel_table()
-                + check_multitenant_section()
-                + check_workload_table())
+    problems = [p for check in CHECKS for p in check()]
     for p in problems:
         print(f"FAIL: {p}")
     if problems:
