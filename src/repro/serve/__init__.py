@@ -3,6 +3,8 @@
 * ``reach_service`` — ``ReachabilityService``: typed requests, futures,
   admission micro-batching and version-keyed snapshot reuse over any
   ``ReachabilityEngine`` backend;
+* ``record`` — ``RequestFuture``: the one record an in-flight request
+  lives in (the caller's future, the queue entry, what dispatch resolves);
 * ``scheduler`` — the weighted-fair multi-tenant admission scheduler;
 * ``replicas`` — ``ReplicaGroup``: one engine's snapshot served from
   several devices.

@@ -72,11 +72,13 @@ docs/ARCHITECTURE.md are CI-checked against ``REQUEST_TYPES``,
 from __future__ import annotations
 
 import dataclasses
+import gc
+import itertools
 import operator
 import queue as queue_mod
 import threading
 import time
-from concurrent.futures import Future, InvalidStateError
+from concurrent.futures import Future
 from typing import (Callable, Dict, Iterable, Iterator, List, Optional,
                     Sequence, Tuple)
 
@@ -84,8 +86,9 @@ import numpy as np
 
 from repro.core.engine import SnapshotUnsupported, WorkloadUnsupported
 from repro.core.query import KernelSnapshot
+from repro.serve.record import RequestFuture, claim, fail, resolve
 from repro.serve.scheduler import (PRIORITY_CLASSES, DeadlineExceeded,
-                                   TenantSpec, WeightedFairScheduler, _Entry)
+                                   TenantSpec, WeightedFairScheduler)
 from repro.stages import Stages, StageTotal
 
 __all__ = ["Request", "MRRequest", "SReachRequest", "WitnessRequest",
@@ -211,6 +214,8 @@ REQUEST_TYPES: Dict[str, type] = {MRRequest.kind: MRRequest,
                                   TopSRequest.kind: TopSRequest,
                                   SDistanceRequest.kind: SDistanceRequest}
 
+_REQUEST_CLASSES = tuple(REQUEST_TYPES.values())
+
 # workload kinds gate on engine.workload_capability at submit; "mr" and
 # "s_reach" (the padded-bucket kinds) every backend serves
 _KIND_TO_OP: Dict[str, str] = {"witness": "witness",
@@ -320,16 +325,31 @@ class ServiceStats:
         return d
 
 
-def _resolve(fut: Future, value) -> None:
-    """Resolve one future, tolerating a caller's concurrent ``cancel()``
-    (a bare ``cancelled()`` pre-check races: the cancel can land between
-    the check and ``set_result``, and the resulting InvalidStateError
-    would poison the whole micro-batch through the dispatch error
-    handler)."""
+def _vertex(x, n: int) -> int:
+    """``x`` as a vertex id in ``[0, n)``, or the error
+    ``validate_batch`` raises."""
     try:
-        fut.set_result(value)
-    except InvalidStateError:
-        pass                         # cancelled mid-dispatch: drop quietly
+        i = operator.index(x)
+    except TypeError:
+        raise ValueError(
+            f"request vertex ids must have an integer dtype; got "
+            f"{x!r}") from None
+    if not 0 <= i < n:
+        raise IndexError(f"request vertex id {i} out of range [0, {n})")
+    return i
+
+
+def _count(x, name: str) -> int:
+    """``x`` as a count of at least 1 (a request's ``k``)."""
+    try:
+        i = operator.index(x)
+    except TypeError:
+        raise ValueError(
+            f"request {name} must have an integer dtype; got "
+            f"{x!r}") from None
+    if i < 1:
+        raise ValueError(f"request {name} must be >= 1; got {i}")
+    return i
 
 
 def _join_form(snap) -> str:
@@ -388,6 +408,13 @@ class ReachabilityService:
     device call through the fetch of its answers; meta ``bucket``) and
     ``resolve`` (futures and their callbacks; meta ``q``).  ``update``
     is timed on the updating thread while it holds the dispatch lock.
+    ``gc`` counts the garbage collector's full passes while the service
+    runs (from ``start()`` to ``close()``), on whichever thread they
+    fell: a counter with no annotation.
+
+    Each submitted request lives in one ``RequestFuture``
+    (``repro.serve.record``): the future the caller holds is the entry
+    the scheduler queues and the record dispatch resolves.
     """
 
     # ReplicaGroup flips this; a plain service refuses a replicated
@@ -442,6 +469,7 @@ class ReachabilityService:
         self._kernel_snap: Optional[KernelSnapshot] = None
         self._running = False
         self._thread: Optional[threading.Thread] = None
+        self._gc_started: Optional[Tuple[int, int]] = None
         if start:
             self.start()
 
@@ -452,6 +480,7 @@ class ReachabilityService:
             if self._running:
                 return self
             self._running = True
+        gc.callbacks.append(self._on_gc)
         self._thread = threading.Thread(target=self._loop,
                                         name="reach-service", daemon=True)
         self._thread.start()
@@ -467,7 +496,24 @@ class ReachabilityService:
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         self.drain()                 # no-thread mode: flush synchronously
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: time each full pass into the ``gc``
+        stage (passes do not overlap, and one starts and stops on the
+        same thread)."""
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._gc_started = (time.perf_counter_ns(),
+                                time.thread_time_ns())
+        elif self._gc_started is not None:
+            wall, cpu = self._gc_started
+            self._gc_started = None
+            self._stages.add("gc", time.perf_counter_ns() - wall,
+                             time.thread_time_ns() - cpu)
 
     def __enter__(self) -> "ReachabilityService":
         return self
@@ -492,12 +538,14 @@ class ReachabilityService:
 
         ``on_result`` is the callback delivery hook: called as
         ``on_result(request, future)`` the moment this request's future
-        resolves (from the dispatching thread), whatever the outcome.
+        resolves (from the dispatching thread, or from the thread that
+        cancels it), whatever the outcome.  The returned future is the
+        request's ``RequestFuture`` record.
 
         Validation is the same contract as ``validate_batch`` (integer
         ids in ``[0, n)``) on a scalar fast path — admission is the
         per-request hot loop, so it avoids array round-trips."""
-        if not isinstance(request, tuple(REQUEST_TYPES.values())):
+        if not isinstance(request, _REQUEST_CLASSES):
             raise TypeError(
                 f"expected one of {sorted(REQUEST_TYPES)} requests, got "
                 f"{type(request).__name__}")
@@ -523,21 +571,17 @@ class ReachabilityService:
                 raise ValueError(
                     f"deadline_ms must be > 0 (or None); got "
                     f"{request.deadline_ms!r}")
-        fut: Future = Future()
-        if on_result is not None:
-            fut.add_done_callback(
-                lambda f, _cb=on_result, _req=request: _cb(_req, f))
         now = time.monotonic()
         expiry = None if deadline_ms is None else now + deadline_ms / 1e3
-        entry = _Entry(request, fut, now, expiry)
+        record = RequestFuture(request, now, expiry, on_result)
         with self._cv:
-            self._queue.push(entry)
+            self._queue.push(record)
             self._stats.submitted += 1
             t = request.tenant
             self._stats.tenant_submitted[t] = \
                 self._stats.tenant_submitted.get(t, 0) + 1
             self._cv.notify()
-        return fut
+        return record
 
     def _validate_fields(self, request: Request) -> None:
         """Per-kind query-field validation (the shared tenant/priority/
@@ -545,30 +589,6 @@ class ReachabilityService:
         with the same contract as ``validate_batch``."""
         n = self.engine.h.n
         kind = request.kind
-
-        def _vertex(x) -> int:
-            try:
-                i = operator.index(x)
-            except TypeError:
-                raise ValueError(
-                    f"request vertex ids must have an integer dtype; got "
-                    f"{x!r}") from None
-            if not 0 <= i < n:
-                raise IndexError(
-                    f"request vertex id {i} out of range [0, {n})")
-            return i
-
-        def _count(x, name: str) -> int:
-            try:
-                i = operator.index(x)
-            except TypeError:
-                raise ValueError(
-                    f"request {name} must have an integer dtype; got "
-                    f"{x!r}") from None
-            if i < 1:
-                raise ValueError(f"request {name} must be >= 1; got {i}")
-            return i
-
         if kind == "mr_set":
             for name, ids in (("us", request.us), ("vs", request.vs)):
                 if not ids:
@@ -576,10 +596,10 @@ class ReachabilityService:
                         f"mr_set request field {name!r} must be a non-empty "
                         f"vertex set")
                 for x in ids:
-                    _vertex(x)
+                    _vertex(x, n)
             return
         if kind == "top_s":
-            _vertex(request.u)
+            _vertex(request.u, n)
             _count(request.k, "k")
             return
         # every remaining kind is a (u, v) pair query
@@ -622,12 +642,16 @@ class ReachabilityService:
         drained inline after submission, so iteration still completes
         without a background thread."""
         done: "queue_mod.Queue[Tuple[Request, Future]]" = queue_mod.Queue()
-        pairs = [(r, self.submit(
-            r, on_result=lambda req, fut, _q=done: _q.put((req, fut))))
-            for r in requests]
+
+        def put(req: Request, fut: Future) -> None:
+            done.put((req, fut))
+        count = 0
+        for r in requests:
+            self.submit(r, on_result=put)
+            count += 1
         if not self._running:
             self.drain()
-        for _ in range(len(pairs)):
+        for _ in range(count):
             yield done.get()
 
     def mr(self, u: int, v: int) -> Future:
@@ -747,87 +771,88 @@ class ReachabilityService:
 
     def drain(self, max_batches: Optional[int] = None) -> int:
         """Synchronously dispatch pending requests in the caller's
-        thread; returns the number of requests resolved (answered or
-        deadline-failed).  This is the deterministic serving mode
-        (``start=False``).  ``max_batches`` bounds the number of
-        micro-batches taken — the fairness tests step one batch at a
-        time to observe its composition."""
+        thread; returns the number of requests taken off the queue
+        (answered, deadline-failed, or cancelled while queued).  This is
+        the deterministic serving mode (``start=False``).
+        ``max_batches`` bounds the number of micro-batches taken — the
+        fairness tests step one batch at a time to observe its
+        composition."""
         total = 0
         batches = 0
         while max_batches is None or batches < max_batches:
-            seq, batch, expired = self._take()
-            if not batch and not expired:
+            seq, batch, swept = self._take()
+            if not batch and not swept:
                 return total
             if batch:
                 self._dispatch(batch, seq)
                 batches += 1
-            total += len(batch) + len(expired)
+            total += len(batch) + swept
         return total
 
-    def _take(self) -> Tuple[int, List[_Entry], List[_Entry]]:
-        """Select the next micro-batch off the queue and fail the
-        requests whose deadline passed.  Returns the take's number, the
-        batch and the expired entries.  Adds the batch's time in the
-        queue to ``queue_wait_s``: one subtraction per request."""
+    def _take(self) -> Tuple[int, List[RequestFuture], int]:
+        """Select the next micro-batch off the queue, claim it (its
+        records can no longer be cancelled; those cancelled while queued
+        are left out) and fail the requests whose deadline passed.
+        Returns the take's number, the batch and how many records the
+        take swept off the queue without a dispatch.  Adds the batch's
+        time in the queue to ``queue_wait_s``: one subtraction per
+        request."""
         self._batch_seq += 1
         seq = self._batch_seq
         with self._stages.span("take", batch=seq) as span:
             with self._cv:
                 now = time.monotonic()
-                batch, expired = self._queue.take(self.max_batch, now)
+                taken, expired = self._queue.take(self.max_batch, now)
+            batch = claim(taken)
             waited = 0.0
-            for entry in batch:
-                waited += now - entry.enqueued
+            for record in batch:
+                waited += now - record.enqueued
             if batch:
                 with self._dispatch_lock:
                     self._stats.queued += len(batch)
                     self._stats.queue_wait_s += waited
             self._fail_expired(expired)
             span.set(taken=len(batch), wait_s=waited)
-        return seq, batch, expired
+        return seq, batch, len(taken) - len(batch) + len(expired)
 
-    def _fail_expired(self, expired: List[_Entry]) -> None:
+    def _fail_expired(self, expired: List[RequestFuture]) -> None:
         if not expired:
             return
         now = time.monotonic()
         with self._dispatch_lock:
             self._stats.expired += len(expired)
-            for entry in expired:
-                t = entry.request.tenant
+            for record in expired:
+                t = record.request.tenant
                 self._stats.tenant_expired[t] = \
                     self._stats.tenant_expired.get(t, 0) + 1
-        for entry in expired:
-            waited_ms = (now - entry.enqueued) * 1e3
-            try:
-                entry.future.set_exception(
-                    DeadlineExceeded(entry.request, waited_ms))
-            except InvalidStateError:
-                pass                 # cancelled while queued: drop quietly
+        # a record cancelled while queued keeps its outcome
+        fail(expired, [DeadlineExceeded(r.request, (now - r.enqueued) * 1e3)
+                       for r in expired])
 
     # -- dispatch ----------------------------------------------------------
 
-    def _dispatch(self, batch: List[_Entry], seq: int) -> None:
+    def _dispatch(self, batch: List[RequestFuture], seq: int) -> None:
         try:
             with self._stages.span("dispatch", batch=seq), \
                     self._dispatch_lock:
                 with self._stages.span("refresh"):
                     snap = self._refresh_snapshot()
-                groups: Dict[str, List[_Entry]] = {}
-                for entry in batch:
-                    groups.setdefault(entry.request.kind, []).append(entry)
+                groups: Dict[str, List[RequestFuture]] = {}
+                for record in batch:
+                    groups.setdefault(record.request.kind, []).append(record)
                 for kind, group in groups.items():
                     self._dispatch_group(kind, group, snap)
                 self._stats.answered += len(batch)
-                for entry in batch:
-                    t = entry.request.tenant
+                for record in batch:
+                    t = record.request.tenant
                     self._stats.tenant_answered[t] = \
                         self._stats.tenant_answered.get(t, 0) + 1
         except Exception as exc:                       # noqa: BLE001
-            for entry in batch:
-                if not entry.future.done():
-                    entry.future.set_exception(exc)
+            # every record the failed dispatch had not answered yet
+            fail(batch, itertools.repeat(exc))
 
-    def _dispatch_group(self, kind: str, group: List[_Entry], snap) -> None:
+    def _dispatch_group(self, kind: str, group: List[RequestFuture],
+                        snap) -> None:
         if kind in _KIND_TO_OP:
             self._dispatch_workload_group(kind, group)
             return
@@ -862,8 +887,7 @@ class ReachabilityService:
                 else:
                     mr = np.asarray(self.engine.mr_batch(us, vs))[:q]
             with stages.span("resolve", q=q):
-                for entry, val in zip(group, mr):
-                    _resolve(entry.future, int(val))
+                resolve(group, mr.astype(np.int64, copy=False).tolist())
             return
 
         with stages.span("join", bucket=bucket, form=form):
@@ -878,10 +902,10 @@ class ReachabilityService:
             else:
                 ok = np.asarray(self.engine.mr_batch(us, vs))[:q] >= svals
         with stages.span("resolve", q=q):
-            for entry, val in zip(group, ok):
-                _resolve(entry.future, bool(val))
+            resolve(group, ok.astype(bool, copy=False).tolist())
 
-    def _dispatch_workload_group(self, kind: str, group: List[_Entry]) -> None:
+    def _dispatch_workload_group(self, kind: str,
+                                 group: List[RequestFuture]) -> None:
         """Workload kinds dispatch per-request through the engine's
         workload methods — witness reconstruction and the BFS-gated ops
         are host-side, while ``mr_set`` / ``top_s`` batch internally
@@ -893,8 +917,8 @@ class ReachabilityService:
         self._stats.batches += 1
         self._stats.workload_answered[kind] = \
             self._stats.workload_answered.get(kind, 0) + len(group)
-        for entry in group:
-            r = entry.request
+        for record in group:
+            r = record.request
             if kind == "witness":
                 val = eng.mr_witness(r.u, r.v)
             elif kind == "s_reach_k":
@@ -907,7 +931,7 @@ class ReachabilityService:
                 val = tuple(zip(verts.tolist(), vals.tolist()))
             else:                    # s_distance (admission pinned kinds)
                 val = int(eng.s_distance(r.u, r.v, r.s))
-            _resolve(entry.future, val)
+            resolve((record,), (val,))
 
     # -- snapshot lifecycle ------------------------------------------------
 

@@ -33,8 +33,9 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict, deque
-from concurrent.futures import Future
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Tuple
+
+from repro.serve.record import RequestFuture
 
 __all__ = ["PRIORITY_CLASSES", "TenantSpec", "DeadlineExceeded",
            "WeightedFairScheduler"]
@@ -84,17 +85,6 @@ class DeadlineExceeded(TimeoutError):
             f"{request.deadline_ms!r})")
 
 
-@dataclasses.dataclass
-class _Entry:
-    """One queued request: the future to resolve plus scheduling state
-    (absolute expiry precomputed so ``take`` compares, not adds)."""
-
-    request: object
-    future: Future
-    enqueued: float                     # time.monotonic() at submit
-    expiry: Optional[float]             # absolute monotonic deadline
-
-
 class WeightedFairScheduler:
     """Two-level admission queue: strict priority bands, deficit-weighted
     round-robin (DRR) across tenants within each band, FIFO within a
@@ -126,7 +116,8 @@ class WeightedFairScheduler:
             self._weights[spec.name] = spec.weight
         # band -> tenant -> FIFO queue; OrderedDict keeps the round-robin
         # order deterministic (insertion order of first pending request)
-        self._bands: Dict[int, "OrderedDict[str, Deque[_Entry]]"] = {}
+        self._bands: Dict[int,
+                          "OrderedDict[str, Deque[RequestFuture]]"] = {}
         self._deficit: Dict[Tuple[int, str], float] = {}
         self._size = 0
 
@@ -136,18 +127,20 @@ class WeightedFairScheduler:
     def __len__(self) -> int:
         return self._size
 
-    def push(self, entry: _Entry) -> None:
+    def push(self, entry: RequestFuture) -> None:
         req = entry.request
         band = PRIORITY_CLASSES[req.priority]
-        tenants = self._bands.setdefault(band, OrderedDict())
+        tenants = self._bands.get(band)
+        if tenants is None:
+            tenants = self._bands[band] = OrderedDict()
         queue = tenants.get(req.tenant)
         if queue is None:
             queue = tenants[req.tenant] = deque()
         queue.append(entry)
         self._size += 1
 
-    def take(self, limit: int,
-             now: float) -> Tuple[List[_Entry], List[_Entry]]:
+    def take(self, limit: int, now: float,
+             ) -> Tuple[List[RequestFuture], List[RequestFuture]]:
         """Select up to ``limit`` entries for the next micro-batch.
 
         Returns ``(selected, expired)``: ``selected`` in dispatch order
@@ -157,8 +150,8 @@ class WeightedFairScheduler:
         share.  Each full DRR pass over a band's backlogged tenants
         accrues ``quantum * weight`` credit per tenant, so the loop
         always progresses (weights are validated > 0)."""
-        selected: List[_Entry] = []
-        expired: List[_Entry] = []
+        selected: List[RequestFuture] = []
+        expired: List[RequestFuture] = []
         if limit < 1:
             return selected, expired
         for band in sorted(self._bands):

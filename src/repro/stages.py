@@ -123,7 +123,9 @@ class Stages:
         self.prefix = prefix
         self._local = threading.local()
         self._threads: List[Dict[str, List[int]]] = []
-        self._lock = threading.Lock()
+        # reentrant: a ``gc.callbacks`` hook may ``add`` between any two
+        # bytecodes, inside ``totals`` or ``_mine`` on the same thread
+        self._lock = threading.RLock()
 
     def _mine(self) -> Dict[str, List[int]]:
         try:

@@ -10,7 +10,6 @@ pinned to the independent MSTOracle.
 """
 import dataclasses
 import time
-from concurrent.futures import Future
 
 import numpy as np
 import pytest
@@ -22,11 +21,12 @@ from repro.api import (DeadlineExceeded, MRRequest, PRIORITY_CLASSES,
 from repro.core import MSTOracle
 from repro.core.distributed import default_line_graph_mesh
 from repro.core.engine import SnapshotUnsupported
-from repro.serve.scheduler import WeightedFairScheduler, _Entry
+from repro.serve.record import RequestFuture
+from repro.serve.scheduler import WeightedFairScheduler
 
 
 def _entry(req, expiry=None, now=0.0):
-    return _Entry(req, Future(), now, expiry)
+    return RequestFuture(req, now, expiry)
 
 
 def _oracle_check(h, reqs, futs):

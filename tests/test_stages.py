@@ -81,6 +81,24 @@ def test_add_accumulates_caller_measured_time_and_totals_is_a_copy():
     assert snap["x"].count == 5 and st.totals()["x"].count == 6
 
 
+def test_add_from_inside_the_lock_on_a_new_thread_does_not_deadlock():
+    # a gc.callbacks hook can run between any two bytecodes, so ``add``
+    # may come while this same thread holds the lock (in ``totals``)
+    st = Stages("t")
+    st.add("before", 1)
+
+    def nested():
+        with st._lock:
+            st.totals()
+            st.add("gc", 5_000_000, 4_000_000)
+
+    t = threading.Thread(target=nested, daemon=True)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert st.totals()["gc"] == StageTotal(1, 0.005, 1, 0.004, 0.005)
+
+
 def test_cpu_clock_is_read_on_every_32nd_run_or_on_long_stages():
     st = Stages("repro.test")
     for _ in range(100):
